@@ -186,6 +186,8 @@ def main():
             print(name)
         return
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     os.makedirs(args.out, exist_ok=True)
     results = {}
     t_start = time.time()

@@ -25,6 +25,7 @@ so the accuracy comparison is kernel-vs-kernel.
 import argparse
 
 from repro.launch import cli
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import snn
 from repro.train.stdp_trainer import train_to_accuracy
 
@@ -35,6 +36,7 @@ def main():
     cli.add_update_flags(ap)
     cli.add_train_flags(ap)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = cli.snn_config_from_args(args)
     tcfg = cli.trainer_config_from_args(args)

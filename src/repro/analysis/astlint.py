@@ -48,12 +48,12 @@ class Finding:
 RULE_EXPLAIN = {
     "R1": """\
 R1: `shard_map` may only be touched inside repro/distributed/sharding.py.
-The pinned jax (0.4.37) has no `jax.shard_map`; newer toolchains deprecate
-`jax.experimental.shard_map` and change the manual-axes keywords
-(`auto=` vs `axis_names=`/`check_vma=`).  `shard_map_compat` in
-repro/distributed/sharding.py is the single version shim — every other
-reference to the raw name is a latent AttributeError on one toolchain or
-the other (train_step.py:129 shipped exactly that bug).""",
+Its keywords have changed across jax releases (`auto=`/`check_rep=` on
+the old `jax.experimental.shard_map`, `axis_names=`/`check_vma=` on
+`jax.shard_map`).  `shard_map_compat` in repro/distributed/sharding.py
+is the single call site that knows them — every other reference to the
+raw name re-opens that break (train_step.py:129 shipped exactly that
+bug).""",
     "R2": """\
 R2: `repro.kernels.itp_*` packages are importable only by the plasticity
 rules and by kernel packages themselves.

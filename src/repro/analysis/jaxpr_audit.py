@@ -26,6 +26,7 @@ import collections
 from typing import Any, Iterable
 
 import jax
+import jax.extend.core as jex_core
 import jax.numpy as jnp
 
 from repro import plasticity
@@ -101,9 +102,9 @@ def _cell_program(rule: str, backend: str, kind: str):
 def _sub_jaxprs(value: Any):
     """Recursively yield jaxprs hiding in an eqn param value (pjit/cond/
     scan/pallas_call all stash them under different shapes)."""
-    if isinstance(value, jax.core.ClosedJaxpr):
+    if isinstance(value, jex_core.ClosedJaxpr):
         yield value.jaxpr
-    elif isinstance(value, jax.core.Jaxpr):
+    elif isinstance(value, jex_core.Jaxpr):
         yield value
     elif isinstance(value, (list, tuple)):
         for v in value:

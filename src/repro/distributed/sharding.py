@@ -28,25 +28,17 @@ _state = threading.local()
 
 
 def shard_map_compat(f, *, mesh, in_specs, out_specs, axis_names=None):
-    """Version-compat ``shard_map``: the top-level ``jax.shard_map`` API
-    (``check_vma``/``axis_names``) when this jax has it, else the
-    ``jax.experimental.shard_map`` API (``check_rep``; partial-manual
-    ``axis_names`` translates to its ``auto`` complement).  The single
-    shim every shard_map call site (``repro.core.engine_sharded``, the
-    multi-device subprocess tests) routes through, so the supported-API
-    decision lives in exactly one place.
+    """``jax.shard_map`` with replication checking off; ``axis_names``
+    selects the manual axes of a partial-manual map.  The single shim
+    every shard_map call site (``repro.core.engine_sharded``, the
+    multi-pod train step, the multi-device subprocess tests) routes
+    through, so the call convention lives in exactly one place.
     """
-    if hasattr(jax, "shard_map"):
-        kw = {"check_vma": False}
-        if axis_names is not None:
-            kw["axis_names"] = axis_names
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as _sm
-    kw = {"check_rep": False}
+    kw = {"check_vma": False}
     if axis_names is not None:
-        kw["auto"] = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
+        kw["axis_names"] = axis_names
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kw)
 
 
 def current_mesh() -> Mesh | None:
@@ -142,13 +134,10 @@ def _manual_axes() -> frozenset:
     multi-pod train step) activation constraints must not mention the
     manual axes — the local shard has no pod dimension.
     """
-    try:
-        am = jax.sharding.get_abstract_mesh()
-        return frozenset(
-            name for name, t in zip(am.axis_names, am.axis_types)
-            if "Manual" in str(t))
-    except Exception:  # pragma: no cover - very old jax
-        return frozenset()
+    am = jax.sharding.get_abstract_mesh()
+    return frozenset(
+        name for name, t in zip(am.axis_names, am.axis_types)
+        if t == jax.sharding.AxisType.Manual)
 
 
 def _strip_manual(ax, manual):

@@ -117,12 +117,13 @@ def _counter_stdp_kernel(
     post_t, post_valid = counter_delays(post_word_ref[...], depth)  # (1, TQ)
 
     # per-pair Δt: broadcast the counter words across the synapse tile —
-    # LTP pairs read the presynaptic delay, LTD pairs the postsynaptic one
-    dt_ltp = jnp.broadcast_to(pre_t[0][:, None], (tp, tq))
-    dt_ltd = jnp.broadcast_to(post_t[0][None, :], (tp, tq))
+    # LTP pairs read the presynaptic delay, LTD pairs the postsynaptic one;
+    # the pre side turns into a (TP, 1) column by a transpose of its row
+    dt_ltp = jnp.broadcast_to(jnp.transpose(pre_t), (tp, tq))
+    dt_ltd = jnp.broadcast_to(post_t, (tp, tq))
     ltp_mag = _pair_window(
         dt_ltp,
-        jnp.broadcast_to(pre_valid[0][:, None], (tp, tq)),
+        jnp.broadcast_to(jnp.transpose(pre_valid), (tp, tq)),
         lut_ref,
         0,
         window=window,
@@ -132,7 +133,7 @@ def _counter_stdp_kernel(
     )
     ltd_mag = _pair_window(
         dt_ltd,
-        jnp.broadcast_to(post_valid[0][None, :], (tp, tq)),
+        jnp.broadcast_to(post_valid, (tp, tq)),
         lut_ref,
         1,
         window=window,
@@ -142,11 +143,11 @@ def _counter_stdp_kernel(
     )
 
     # XOR/AND control logic (§V-A), arithmetic form on {0,1}
-    pre_s = pre_spike_ref[...].astype(jnp.float32)  # (1, TP)
+    pre_col = jnp.transpose(pre_spike_ref[...].astype(jnp.float32))  # (TP, 1)
     post_s = post_spike_ref[...].astype(jnp.float32)  # (1, TQ)
-    xor = pre_s[0, :, None] + post_s[0, None, :] - 2.0 * pre_s[0, :, None] * post_s[0, None, :]
-    ltp_en = xor * post_s[0, None, :]  # post fired alone
-    ltd_en = xor * pre_s[0, :, None]  # pre fired alone
+    xor = pre_col + post_s - 2.0 * pre_col * post_s
+    ltp_en = xor * post_s  # post fired alone
+    ltd_en = xor * pre_col  # pre fired alone
 
     dw = ltp_en * ltp_mag - ltd_en * ltd_mag
     out_ref[...] = jnp.clip(w_ref[...] + eta * dw, w_min, w_max)
@@ -235,11 +236,8 @@ def counter_stdp_update(
             pl.BlockSpec((1, tq), lambda i, j: (0, j)),  # post_spike
             pl.BlockSpec((1, tp), lambda i, j: (0, i)),  # pre counter words
             pl.BlockSpec((1, tq), lambda i, j: (0, j)),  # post counter words
-            pl.BlockSpec(  # window LUT: scalar rows in SMEM
-                (2, depth),
-                lambda i, j: (0, 0),
-                memory_space=pltpu.TPUMemorySpace.SMEM,
-            ),
+            # window LUT: scalar rows in SMEM
+            pl.BlockSpec((2, depth), lambda i, j: (0, 0), memory_space=pltpu.SMEM),
             pl.BlockSpec((tp, tq), lambda i, j: (i, j)),  # w
         ],
         out_specs=pl.BlockSpec((tp, tq), lambda i, j: (i, j)),
@@ -304,8 +302,13 @@ def _counter_conv_kernel(
     contract = (((0,), (0,)), ((), ()))
     ltp_term = (1.0 - pre) * ltp_mag  # (TM, K)
     ltd_term = (1.0 - post) * ltd_mag  # (TM, C)
-    dw_ltp = jax.lax.dot_general(ltp_term, post, contract, preferred_element_type=jnp.float32)
-    dw_ltd = jax.lax.dot_general(pre, ltd_term, contract, preferred_element_type=jnp.float32)
+    hi = jax.lax.Precision.HIGHEST
+    dw_ltp = jax.lax.dot_general(
+        ltp_term, post, contract, precision=hi, preferred_element_type=jnp.float32
+    )
+    dw_ltd = jax.lax.dot_general(
+        pre, ltd_term, contract, precision=hi, preferred_element_type=jnp.float32
+    )
 
     @pl.when(pl.program_id(0) == 0)
     def _init():
@@ -383,11 +386,8 @@ def counter_conv_delta(
             pl.BlockSpec((tm, cc), lambda i: (i, 0)),  # post spikes
             pl.BlockSpec((tm, kk), lambda i: (i, 0)),  # pre counter words
             pl.BlockSpec((tm, cc), lambda i: (i, 0)),  # post counter words
-            pl.BlockSpec(  # window LUT: scalar rows in SMEM
-                (2, depth),
-                lambda i: (0, 0),
-                memory_space=pltpu.TPUMemorySpace.SMEM,
-            ),
+            # window LUT: scalar rows in SMEM
+            pl.BlockSpec((2, depth), lambda i: (0, 0), memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((kk, cc), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((kk, cc), jnp.float32),

@@ -18,10 +18,16 @@ Layout choices (HW-codesign reasoning):
     product accumulate — MXU-aligned when TP, TQ are multiples of 8/128.
 
 The kernel covers both pairing modes of §II-B with one code path: the
-nearest-neighbour MSB mask (Fig. 11) is ``bits & (cumsum(bits) == 1)``,
-the all-to-all fixed-point read (Fig. 3) uses the raw bits; both then dot
-with the po2 vector, which carries the place values 2^(-k/τ') (place value
-2^-k exactly in the hardware regime τ' = 1).
+nearest-neighbour MSB mask (Fig. 11) keeps the first '1' of each register
+(:func:`first_one_mask`), the all-to-all fixed-point read (Fig. 3) uses
+the raw bits; both then dot with the po2 vector, which carries the place
+values 2^(-k/τ') (place value 2^-k exactly in the hardware regime τ' = 1).
+
+Every op in the bodies must lower through Mosaic, which has no ``cumsum``
+and no row→column gather: the MSB mask is an unrolled scan and pre-side
+columns are transposes of the ``(1, T)`` rows.  The po2 dot asks for full
+f32 contract precision; at Mosaic's default a single bf16 MXU pass would
+round each place value (2^-9 relative).
 """
 from __future__ import annotations
 
@@ -45,6 +51,22 @@ def _unpack_bits(words: jax.Array, depth: int) -> jax.Array:
     return jnp.concatenate(planes, axis=0).astype(jnp.float32)
 
 
+def first_one_mask(bits: jax.Array) -> jax.Array:
+    """Fig. 11 MSB priority encode on {0,1} planes stacked along axis 0.
+
+    Keeps only the first '1' scanning most-recent-first — exactly
+    ``bits * (cumsum(bits, axis=0) == 1)`` on {0,1} — as an unrolled scan
+    over the static depth (≤ 8) with a running "seen" plane.
+    """
+    seen = jnp.zeros_like(bits[0:1])
+    rows = []
+    for k in range(bits.shape[0]):
+        row = bits[k:k + 1] * (1.0 - seen)
+        rows.append(row)
+        seen = seen + row
+    return jnp.concatenate(rows, axis=0)
+
+
 def _stdp_body(pre_bits, post_bits, pre_spike_ref, post_spike_ref,
                po2_ltp_ref, po2_ltd_ref, w_ref, out_ref, *,
                nearest: bool, eta: float, w_min: float, w_max: float):
@@ -55,24 +77,24 @@ def _stdp_body(pre_bits, post_bits, pre_spike_ref, post_spike_ref,
     construction.
     """
     if nearest:
-        # Fig. 11 MSB mask: keep only the first '1' scanning most-recent-first
-        pre_bits = pre_bits * (jnp.cumsum(pre_bits, axis=0) == 1.0)
-        post_bits = post_bits * (jnp.cumsum(post_bits, axis=0) == 1.0)
+        pre_bits = first_one_mask(pre_bits)
+        post_bits = first_one_mask(post_bits)
 
     # po2 read: (1, depth) @ (depth, T) -> (1, T); the 'register read IS the
     # weight update' step.  po2 vectors include the A± amplitudes.
-    ltp_mag = po2_ltp_ref[...] @ pre_bits        # (1, TP)
-    ltd_mag = po2_ltd_ref[...] @ post_bits       # (1, TQ)
+    hi = jax.lax.Precision.HIGHEST
+    ltp_mag = jnp.dot(po2_ltp_ref[...], pre_bits, precision=hi)    # (1, TP)
+    ltd_mag = jnp.dot(po2_ltd_ref[...], post_bits, precision=hi)   # (1, TQ)
 
     # XOR/AND control logic (§V-A): update only when exactly one side fired
     pre_s = pre_spike_ref[...].astype(jnp.float32)     # (1, TP)
     post_s = post_spike_ref[...].astype(jnp.float32)   # (1, TQ)
-    fire_xor = pre_s[0, :, None] + post_s[0, None, :] \
-             - 2.0 * pre_s[0, :, None] * post_s[0, None, :]   # XOR on {0,1}
-    ltp_en = fire_xor * post_s[0, None, :]       # post fired alone
-    ltd_en = fire_xor * pre_s[0, :, None]        # pre fired alone
+    pre_col = jnp.transpose(pre_s)                     # (TP, 1)
+    fire_xor = pre_col + post_s - 2.0 * pre_col * post_s   # XOR on {0,1}
+    ltp_en = fire_xor * post_s                   # post fired alone
+    ltd_en = fire_xor * pre_col                  # pre fired alone
 
-    dw = ltp_en * ltp_mag[0, :, None] - ltd_en * ltd_mag[0, None, :]
+    dw = ltp_en * jnp.transpose(ltp_mag) - ltd_en * ltd_mag
     out_ref[...] = jnp.clip(w_ref[...] + eta * dw, w_min, w_max)
 
 

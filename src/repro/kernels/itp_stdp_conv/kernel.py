@@ -18,10 +18,14 @@ kernel):
     width K and channel count C are padded to the 128-lane boundary by
     ops.py, so both matmuls are MXU-aligned;
   * bitplanes arrive depth-major (depth, TM, K): the po2 read is a
-    length-depth reduction over the leading axis, kept entirely in VREGs;
+    length-depth multiply-accumulate over the leading axis, one SMEM
+    scalar place value per depth slot, kept entirely in VREGs;
   * the (K, C) delta tile stays resident in VMEM across the whole grid —
     each grid step accumulates its tile's two dot products into it, so the
-    weight delta makes exactly one HBM round-trip.
+    weight delta makes exactly one HBM round-trip;
+  * both matmuls ask for full f32 contract precision: the magnitude
+    operand carries arbitrary f32 place values that a single bf16 MXU
+    pass would round.
 """
 
 from __future__ import annotations
@@ -31,6 +35,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.itp_stdp.kernel import first_one_mask
 
 
 def _unpack_bits(words: jax.Array, depth: int) -> jax.Array:
@@ -46,6 +53,18 @@ def _unpack_bits(words: jax.Array, depth: int) -> jax.Array:
     return jnp.concatenate(planes, axis=0).astype(jnp.float32)
 
 
+def _po2_read(po2_ref, bits: jax.Array) -> jax.Array:
+    """Σ_k po2[k] · bits[k] over the leading depth axis, k ascending.
+
+    ``po2_ref`` is the ``(1, depth)`` place-value row in SMEM: each depth
+    slot reads one scalar and scales its whole plane.
+    """
+    acc = po2_ref[0, 0] * bits[0]
+    for k in range(1, bits.shape[0]):
+        acc = acc + po2_ref[0, k] * bits[k]
+    return acc
+
+
 def _conv_stdp_body(
     pre, post, pre_bits, post_bits, po2_ltp_ref, po2_ltd_ref, out_ref, *, nearest: bool
 ):
@@ -56,25 +75,26 @@ def _conv_stdp_body(
     construction.
     """
     if nearest:
-        # Fig. 11 MSB mask: keep only the first '1' scanning most-recent-first
-        pre_bits = pre_bits * (jnp.cumsum(pre_bits, axis=0) == 1.0)
-        post_bits = post_bits * (jnp.cumsum(post_bits, axis=0) == 1.0)
+        pre_bits = first_one_mask(pre_bits)
+        post_bits = first_one_mask(post_bits)
 
     # po2 read: reduce the depth axis against the place-value vector — the
     # 'register read IS the weight update' step, per patch element
-    depth = pre_bits.shape[0]
-    po2_ltp = po2_ltp_ref[...].reshape(depth, 1, 1)
-    po2_ltd = po2_ltd_ref[...].reshape(depth, 1, 1)
-    ltp_mag = jnp.sum(po2_ltp * pre_bits, axis=0)  # (TM, K)
-    ltd_mag = jnp.sum(po2_ltd * post_bits, axis=0)  # (TM, C)
+    ltp_mag = _po2_read(po2_ltp_ref, pre_bits)  # (TM, K)
+    ltd_mag = _po2_read(po2_ltd_ref, post_bits)  # (TM, C)
 
     # XOR/AND pair gate: potentiate where post fired alone, depress where
     # pre fired alone; contract the patch-row axis on the MXU
     contract = (((0,), (0,)), ((), ()))
     ltp_term = (1.0 - pre) * ltp_mag  # (TM, K)
     ltd_term = (1.0 - post) * ltd_mag  # (TM, C)
-    dw_ltp = jax.lax.dot_general(ltp_term, post, contract, preferred_element_type=jnp.float32)
-    dw_ltd = jax.lax.dot_general(pre, ltd_term, contract, preferred_element_type=jnp.float32)
+    hi = jax.lax.Precision.HIGHEST
+    dw_ltp = jax.lax.dot_general(
+        ltp_term, post, contract, precision=hi, preferred_element_type=jnp.float32
+    )
+    dw_ltd = jax.lax.dot_general(
+        pre, ltd_term, contract, precision=hi, preferred_element_type=jnp.float32
+    )
 
     @pl.when(pl.program_id(0) == 0)
     def _init():
@@ -174,8 +194,8 @@ def itp_stdp_conv_delta(
             pl.BlockSpec((tm, cc), lambda i: (i, 0)),  # post spikes
             pl.BlockSpec((depth, tm, kk), lambda i: (0, i, 0)),  # pre bitplanes
             pl.BlockSpec((depth, tm, cc), lambda i: (0, i, 0)),  # post bitplanes
-            pl.BlockSpec((1, depth), lambda i: (0, 0)),  # po2 LTP read vector
-            pl.BlockSpec((1, depth), lambda i: (0, 0)),  # po2 LTD read vector
+            pl.BlockSpec((1, depth), lambda i: (0, 0), memory_space=pltpu.SMEM),  # po2 LTP
+            pl.BlockSpec((1, depth), lambda i: (0, 0), memory_space=pltpu.SMEM),  # po2 LTD
         ],
         out_specs=pl.BlockSpec((kk, cc), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((kk, cc), jnp.float32),
@@ -250,8 +270,8 @@ def itp_stdp_conv_delta_packed(
             pl.BlockSpec((tm, cc), lambda i: (i, 0)),  # post spikes
             pl.BlockSpec((tm, kk), lambda i: (i, 0)),  # pre packed words
             pl.BlockSpec((tm, cc), lambda i: (i, 0)),  # post packed words
-            pl.BlockSpec((1, depth), lambda i: (0, 0)),  # po2 LTP read vector
-            pl.BlockSpec((1, depth), lambda i: (0, 0)),  # po2 LTD read vector
+            pl.BlockSpec((1, depth), lambda i: (0, 0), memory_space=pltpu.SMEM),  # po2 LTP
+            pl.BlockSpec((1, depth), lambda i: (0, 0), memory_space=pltpu.SMEM),  # po2 LTD
         ],
         out_specs=pl.BlockSpec((kk, cc), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((kk, cc), jnp.float32),

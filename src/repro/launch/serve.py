@@ -21,6 +21,7 @@ import numpy as np
 
 from repro.launch.cli import (add_serve_flags, add_update_flags,
                               engine_config_from_args, serve_config_from_args)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serve import Request, Server
 
 
@@ -49,6 +50,7 @@ def main():
     ap.add_argument("--ckpt-dir", default=None,
                     help="restore latest checkpoint on start, save on exit")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = engine_config_from_args(args)
     scfg = serve_config_from_args(args)

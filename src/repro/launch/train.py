@@ -41,6 +41,7 @@ from repro.distributed.fault_tolerance import (FailureInjector, RunnerConfig,
                                                TrainingRunner)
 from repro.distributed.sharding import use_mesh
 from repro.launch import cli
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import describe, make_debug_mesh
 from repro.train import (OptimizerConfig, TrainConfig, init_training,
                          make_train_step)
@@ -186,6 +187,7 @@ def main():
     ap.add_argument("--inject-failure-at", type=int, default=-1)
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.net:
         run_snn_training(args)

@@ -16,6 +16,7 @@ import numpy as np
 import pytest
 
 from repro.core.engine import EngineConfig, init_engine, run_engine
+from repro.launch.mesh import make_debug_mesh
 from repro.plasticity import Rank1Rule, register_rule
 from repro.plasticity.base import RULES
 
@@ -112,7 +113,7 @@ def test_third_party_rule_crosses_sharded_engine(key, third_party_rules):
     train = jax.random.bernoulli(key, 0.4, (16, cfg.n_pre))
     ref_state, ref_post = run_engine(state0, train, cfg)
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_debug_mesh(data=1, model=1)
     with mesh:
         st = shard_engine_state(init_engine(key, cfg), mesh)
         step = make_sharded_engine_step(cfg, mesh)

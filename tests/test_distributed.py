@@ -20,6 +20,7 @@ from repro.distributed.compression import compression_error
 from repro.distributed.sharding import (kv_cache_spec, logical_to_spec,
                                         param_spec_for)
 from repro.kernels.po2_quant.ref import po2_encode_ref, po2_roundtrip_ref
+from repro.launch.mesh import make_debug_mesh
 
 
 class FakeMesh:
@@ -128,15 +129,15 @@ def test_po2_signs_and_zeros(key):
 # ---------------------------------------------------------------------------
 
 def test_shard_map_compat_single_device():
-    """The shim runs on whichever shard_map API the installed jax has.
+    """The shim runs on ``jax.shard_map``.
 
-    Covers the ``axis_names`` translation (→ ``auto`` on the
-    ``jax.experimental`` API) — the call shape MULTIDEV_SCRIPT uses —
-    and the plain fully-manual form the sharded engine uses.
+    Covers the partial-manual ``axis_names`` form — the call shape
+    MULTIDEV_SCRIPT uses — and the plain fully-manual form the sharded
+    engine uses.
     """
     from repro.distributed.sharding import shard_map_compat
 
-    mesh = jax.make_mesh((1, 1, 1), ("pod", "data", "model"))
+    mesh = make_debug_mesh(pod=1, data=1, model=1)
     x = jnp.arange(8, dtype=jnp.float32).reshape(1, 8)
     out = jax.jit(shard_map_compat(
         lambda g: jax.lax.pmean(g, "pod"),
@@ -167,7 +168,7 @@ def test_train_step_multipod_traces_on_this_toolchain(key):
 
     cfg = get_smoke_config("qwen3-0.6b")
     opt_cfg = OptimizerConfig(total_steps=2)
-    mesh = jax.make_mesh((1, 1, 1), ("pod", "data", "model"))
+    mesh = make_debug_mesh(pod=1, data=1, model=1)
     with use_mesh(mesh):
         step = make_train_step(cfg, opt_cfg, TrainConfig(remat="none"), mesh)
         params = jax.eval_shape(
@@ -197,8 +198,9 @@ MULTIDEV_SCRIPT = textwrap.dedent("""
     from repro.distributed.compression import pod_mean_tree
     from repro.distributed.sharding import shard_map_compat
     from repro.kernels.po2_quant.ref import po2_roundtrip_ref
+    from repro.launch.mesh import make_debug_mesh
 
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_debug_mesh(pod=2, data=2, model=2)
     x = jnp.arange(16, dtype=jnp.float32).reshape(2, 8)   # pod-major rows
 
     def f(g):
@@ -241,6 +243,7 @@ SHARDED_TRAIN_SCRIPT = textwrap.dedent("""
     import numpy as np
     from repro.configs import get_smoke_config
     from repro.distributed.sharding import use_mesh
+    from repro.launch.mesh import make_debug_mesh
     from repro.train import (OptimizerConfig, TrainConfig, init_training,
                              make_train_step)
 
@@ -261,8 +264,8 @@ SHARDED_TRAIN_SCRIPT = textwrap.dedent("""
                 params, opt, m = step(params, opt, batch)
             return float(m["loss"])
 
-    l_single = run(jax.make_mesh((2, 2), ("data", "model")))
-    l_multi = run(jax.make_mesh((2, 2, 2), ("pod", "data", "model")))
+    l_single = run(make_debug_mesh(data=2, model=2))
+    l_multi = run(make_debug_mesh(pod=2, data=2, model=2))
     # same data, same init → pod-compressed run must track closely
     assert abs(l_single - l_multi) / l_single < 0.05, (l_single, l_multi)
     print("TRAIN_OK", l_single, l_multi)
@@ -341,7 +344,7 @@ def test_sharded_engine_parity_single_device(key, backend, rule):
     train = jax.random.bernoulli(key, 0.4, (20, 16))
     ref_state, ref_post = run_engine(state0, train, cfg)
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_debug_mesh(data=1, model=1)
     with mesh:
         st = shard_engine_state(init_engine(key, cfg), mesh)
         step = make_sharded_engine_step(cfg, mesh)
@@ -364,7 +367,7 @@ def test_sharded_engine_quantised_single_device(key):
     state0 = init_engine(key, cfg)
     train = jax.random.bernoulli(key, 0.4, (12, 8))
     ref_state, _ = run_engine(state0, train, cfg)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_debug_mesh(data=1, model=1)
     with mesh:
         st = shard_engine_state(init_engine(key, cfg), mesh)
         step = make_sharded_engine_step(cfg, mesh)
@@ -382,6 +385,7 @@ SHARDED_ENGINE_SCRIPT = textwrap.dedent("""
     from repro.core.engine import EngineConfig, init_engine, run_engine
     from repro.core.engine_sharded import (make_sharded_engine_step,
                                            shard_engine_state)
+    from repro.launch.mesh import make_debug_mesh
 
     cfg = EngineConfig(n_pre=16, n_post=8, eta=0.25)
     key = jax.random.PRNGKey(0)
@@ -392,7 +396,7 @@ SHARDED_ENGINE_SCRIPT = textwrap.dedent("""
     ref_state, ref_post = run_engine(state0, train, cfg)
 
     # distributed: 2-D sharded weights over a (2, 4) mesh
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_debug_mesh(data=2, model=4)
     with mesh:
         st = shard_engine_state(init_engine(key, cfg), mesh)
         step = make_sharded_engine_step(cfg, mesh)

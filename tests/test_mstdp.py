@@ -18,6 +18,7 @@ import pytest
 
 from repro.core import history as H
 from repro.core.engine import EngineConfig, init_engine, run_engine
+from repro.launch.mesh import make_debug_mesh
 from repro.plasticity import MSTDP, MSTDPRule, MSTDPState, get_rule
 from repro.plasticity.base import RULES
 from repro.plasticity.mstdp import ELIG_INJECT, ELIG_MAX
@@ -119,7 +120,7 @@ def test_mstdp_crosses_sharded_engine(key, backend):
     train = jax.random.bernoulli(key, 0.3, (16, cfg.n_pre))
     ref_state, ref_post = run_engine(state0, train, cfg)
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_debug_mesh(data=1, model=1)
     with mesh:
         st = shard_engine_state(init_engine(key, cfg), mesh)
         step = make_sharded_engine_step(cfg, mesh)

@@ -26,6 +26,7 @@ from repro.core.engine import EngineConfig, init_engine, run_engine
 from repro.core.stdp import STDPParams, magnitudes_depth_major, synapse_update
 from repro.kernels.itp_sparse.events import spike_events
 from repro.kernels.itp_sparse.ops import sparse_synapse_delta, sparse_weight_update
+from repro.launch.mesh import make_debug_mesh
 from repro.models import snn
 
 DEPTH = 7
@@ -191,7 +192,7 @@ def test_sharded_engine_sparse_parity_single_device(key, max_events):
     t = 40
     spike_key = jax.random.fold_in(key, 7)
     train = jax.random.bernoulli(spike_key, 0.3, (t, cfg.n_pre)).astype(jnp.float32)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_debug_mesh(data=1, model=1)
     with mesh:
         st = shard_engine_state(state, mesh)
         step = make_sharded_engine_step(cfg, mesh)
