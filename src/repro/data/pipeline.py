@@ -9,13 +9,17 @@ from __future__ import annotations
 
 import collections
 import threading
+from functools import partial
 from typing import Callable, Iterator
 
 import jax
+from jax.profiler import TraceAnnotation, annotate_function
 
+from repro import tracing
 from repro.core.encoding import minmax_normalise, rate_code
 
 
+@partial(annotate_function, name=tracing.ENCODE)
 def encode_batch(key: jax.Array, x: jax.Array, t_steps: int) -> jax.Array:
     """(B, ...) floats → (T, B, features) {0,1} spikes.
 
@@ -36,7 +40,8 @@ def spike_stream(key: jax.Array,
     step = 0
     while n_steps is None or step < n_steps:
         key, k_data, k_enc = jax.random.split(key, 3)
-        x, labels = sampler(k_data, batch)
+        with TraceAnnotation(tracing.SAMPLE):
+            x, labels = sampler(k_data, batch)
         yield {"spikes": encode_batch(k_enc, x, t_steps), "labels": labels}
         step += 1
 
@@ -70,11 +75,13 @@ class Prefetcher:
                 while not self._stop.is_set():
                     with self._lock:
                         if len(self._q) < self._depth:
-                            self._q.append(jax.device_put(item))
+                            with TraceAnnotation(tracing.PREFETCH_PUT):
+                                self._q.append(jax.device_put(item))
                             self._event.set()
                             break
-                    self._space.clear()
-                    self._space.wait(timeout=0.1)
+                    with TraceAnnotation(tracing.PREFETCH_WAIT_SPACE):
+                        self._space.clear()
+                        self._space.wait(timeout=0.1)
         finally:
             self._done = True
             self._event.set()
@@ -111,5 +118,6 @@ class Prefetcher:
                     return item
                 if self._done:
                     raise StopIteration
-            self._event.clear()
-            self._event.wait(timeout=0.1)
+            with TraceAnnotation(tracing.PREFETCH_WAIT_ITEM):
+                self._event.clear()
+                self._event.wait(timeout=0.1)

@@ -230,6 +230,7 @@ def counter_stdp_update(
     )
     return pl.pallas_call(
         kern,
+        name="counter_stdp_update",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, tp), lambda i, j: (0, i)),  # pre_spike
@@ -380,6 +381,7 @@ def counter_conv_delta(
     )
     return pl.pallas_call(
         kern,
+        name="counter_conv_delta",
         grid=(m // tm,),
         in_specs=[
             pl.BlockSpec((tm, kk), lambda i: (i, 0)),  # pre patches

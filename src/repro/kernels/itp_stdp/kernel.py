@@ -167,6 +167,7 @@ def itp_stdp_update(w: jax.Array,
                              w_min=w_min, w_max=w_max)
     return pl.pallas_call(
         kern,
+        name="itp_stdp_update",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, tp), lambda i, j: (0, i)),        # pre_spike
@@ -249,6 +250,7 @@ def itp_stdp_update_packed(w: jax.Array,
                              w_max=w_max)
     return pl.pallas_call(
         kern,
+        name="itp_stdp_update_packed",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, tp), lambda i, j: (0, i)),        # pre_spike

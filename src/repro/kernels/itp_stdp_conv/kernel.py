@@ -188,6 +188,7 @@ def itp_stdp_conv_delta(
     kern = functools.partial(_conv_stdp_kernel, nearest=nearest)
     return pl.pallas_call(
         kern,
+        name="itp_stdp_conv_delta",
         grid=(m // tm,),
         in_specs=[
             pl.BlockSpec((tm, kk), lambda i: (i, 0)),  # pre patches
@@ -264,6 +265,7 @@ def itp_stdp_conv_delta_packed(
     kern = functools.partial(_conv_stdp_packed_kernel, depth=depth, nearest=nearest)
     return pl.pallas_call(
         kern,
+        name="itp_stdp_conv_delta_packed",
         grid=(m // tm,),
         in_specs=[
             pl.BlockSpec((tm, kk), lambda i: (i, 0)),  # pre patches

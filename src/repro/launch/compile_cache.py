@@ -23,7 +23,13 @@ def enable_compile_cache() -> str:
     Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and no
     other directory is set here.  Otherwise the cache goes to
     ``<checkout>/.jax_cache``.
+
+    Entries are keyed with the ops' metadata.  It carries the device
+    scopes (``repro.tracing``) that a profile reads; a key without it would
+    hand back an executable compiled from other source, with that source's
+    scopes and instruction names.
     """
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env_dir:
         return env_dir

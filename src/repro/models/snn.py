@@ -36,8 +36,9 @@ from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation, annotate_function
 
-from repro import plasticity
+from repro import plasticity, tracing
 from repro.core.lif import (IzhikevichParams, LIFParams, izhikevich_init,
                             izhikevich_step, lif_init, lif_step)
 from repro.core.stdp import STDPParams
@@ -266,6 +267,7 @@ def _fan_in(spec: SNNLayerSpec, in_shape: tuple) -> int:
     return 0
 
 
+@partial(annotate_function, name=tracing.INIT_SNN)
 def init_snn(key: jax.Array, cfg: SNNConfig, batch: int) -> SNNState:
     shapes = _layer_shapes(cfg)
     weights, states = [], []
@@ -280,8 +282,9 @@ def init_snn(key: jax.Array, cfg: SNNConfig, batch: int) -> SNNState:
                                    minval=0.2, maxval=0.8)
             weights.append(w.astype(jnp.float32))
             rule = cfg.learning_rule()
-            n_pre = batch * int(jnp.prod(jnp.asarray(in_shape)))
-            n_post = batch * int(jnp.prod(jnp.asarray(out_shape)))
+            with TraceAnnotation(tracing.HOST_SYNC):
+                n_pre = batch * int(jnp.prod(jnp.asarray(in_shape)))
+                n_post = batch * int(jnp.prod(jnp.asarray(out_shape)))
             states.append(LayerState(
                 neurons=_neuron_init(cfg, (batch,) + out_shape),
                 pre_hist=rule.init_state(n_pre, cfg.depth),
@@ -379,16 +382,17 @@ def _learnable_step(spec: SNNLayerSpec, cfg: SNNConfig, w: jax.Array,
     rule = cfg.learning_rule()
     if train:
         plan = plasticity.make_plan(cfg)
-        if spec.kind != "fc":
-            dw = plan.conv_delta(st.pre_hist, st.post_hist, patches, s_out,
-                                 in_shape=spikes_in.shape[1:],
-                                 kind=spec.kind, kernel=spec.kernel,
-                                 stride=spec.stride)
-        else:
-            dw = plan.fc_delta(st.pre_hist, st.post_hist, s_in, s_out)
-        denom = float(B * patches.shape[1])            # P = 1 for fc
-        w = jnp.clip(w + cfg.eta * dw / denom, 0.0, 1.0)
-        w = _quantise(w, cfg)
+        with jax.named_scope(tracing.UPDATE):
+            if spec.kind != "fc":
+                dw = plan.conv_delta(st.pre_hist, st.post_hist, patches, s_out,
+                                     in_shape=spikes_in.shape[1:],
+                                     kind=spec.kind, kernel=spec.kernel,
+                                     stride=spec.stride)
+            else:
+                dw = plan.fc_delta(st.pre_hist, st.post_hist, s_in, s_out)
+            denom = float(B * patches.shape[1])            # P = 1 for fc
+            w = jnp.clip(w + cfg.eta * dw / denom, 0.0, 1.0)
+            w = _quantise(w, cfg)
 
     # --- homeostasis θ update (training only; frozen during eval) ---------
     theta_new = st.theta
@@ -401,12 +405,11 @@ def _learnable_step(spec: SNNLayerSpec, cfg: SNNConfig, w: jax.Array,
             + cfg.theta_plus * rate
 
     # --- record new spikes (history shift-in / counter reset) ------------
-    st = LayerState(
-        neurons=neurons,
-        pre_hist=rule.step(st.pre_hist, s_in.reshape(-1), depth=cfg.depth),
-        post_hist=rule.step(st.post_hist, s_out.reshape(-1), depth=cfg.depth),
-        theta=theta_new,
-    )
+    with jax.named_scope(tracing.TIMING):
+        pre_hist = rule.step(st.pre_hist, s_in.reshape(-1), depth=cfg.depth)
+        post_hist = rule.step(st.post_hist, s_out.reshape(-1), depth=cfg.depth)
+    st = LayerState(neurons=neurons, pre_hist=pre_hist, post_hist=post_hist,
+                    theta=theta_new)
     return w, st, spikes_out
 
 
@@ -436,16 +439,19 @@ def snn_step(state: SNNState, spikes_in: jax.Array, cfg: SNNConfig,
     new_w, new_l = [], []
     wi = 0
     s = spikes_in
-    for spec, lst in zip(cfg.layers, state.layers):
-        if spec.kind.startswith("pool"):
-            s = _pool_step(spec, s)
-            new_l.append(lst)
-        else:
-            w, lst2, s = _learnable_step(spec, cfg, state.weights[wi], lst, s,
-                                         train)
-            new_w.append(w)
-            new_l.append(lst2)
-            wi += 1
+    # every op of the step is forward work but those under the update and
+    # timing scopes that _learnable_step opens inside this one
+    with jax.named_scope(tracing.FORWARD):
+        for spec, lst in zip(cfg.layers, state.layers):
+            if spec.kind.startswith("pool"):
+                s = _pool_step(spec, s)
+                new_l.append(lst)
+            else:
+                w, lst2, s = _learnable_step(spec, cfg, state.weights[wi], lst,
+                                             s, train)
+                new_w.append(w)
+                new_l.append(lst2)
+                wi += 1
     return SNNState(weights=tuple(new_w), layers=tuple(new_l)), s
 
 
@@ -467,6 +473,7 @@ def run_snn(state: SNNState, raster: jax.Array, cfg: SNNConfig,
     return state, outs.sum(axis=0)
 
 
+@partial(annotate_function, name=tracing.RESET_DYNAMICS)
 def reset_dynamics(state: SNNState, cfg: SNNConfig, batch: int) -> SNNState:
     """Zero neuron states + histories between samples; keep learned weights
     AND the adaptive thresholds θ — homeostasis is the slow variable that

@@ -41,6 +41,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from repro import tracing
 from repro.core.stdp import STDPParams, pair_gate
 from repro.kernels.dispatch import (im2col_1d, im2col_2d, im2col_words_1d,
                                     im2col_words_2d, spike_events)
@@ -277,7 +278,8 @@ class UpdatePlan:
             dw_ltp = jnp.einsum("bpk,bpc->kc", (1.0 - pre_p) * ltp_p, post_s)
             dw_ltd = jnp.einsum("bpk,bpc->kc", pre_p, (1.0 - post_s) * ltd_m)
             return dw_ltp - dw_ltd
-        pre_read, post_read = self._batched_readouts(pre_state, post_state, B)
+        with jax.named_scope(tracing.TIMING):
+            pre_read, post_read = self._batched_readouts(pre_state, post_state, B)
         if self.sparse:
             def one(p, q, pr, qr):
                 return self.rule.sparse_delta_from_readout(
@@ -311,26 +313,27 @@ class UpdatePlan:
         rule = self.rule
         B = s_out.shape[0]
         packed = self.use_kernel and self.packed
-        pre_read = rule.kernel_readout(pre_state, packed=packed)
-        post_read = rule.kernel_readout(post_state, packed=packed)
-        if pre_read.ndim == 1:
-            # per-neuron word readout: im2col the (M, K) uint8 words once
-            im2col_w = im2col_words_2d if kind == "conv2d" else im2col_words_1d
-            pre_read = im2col_w(pre_read.reshape((B,) + tuple(in_shape)),
-                                kernel, stride)
-            pre_read = pre_read.reshape(-1, pre_read.shape[-1])      # (M, K)
-            post_read = post_read.reshape(-1, s_out.shape[-1])       # (M, C)
-        else:
-            # dense row layout: (rows, M, ·) float32 patches
-            im2col = im2col_2d if kind == "conv2d" else im2col_1d
-            rows = pre_read.shape[0]
-            pre_read = pre_read.astype(jnp.float32)
-            pre_read = pre_read.reshape((rows, B) + tuple(in_shape))
-            pre_read = jax.vmap(
-                lambda p: im2col(p, kernel, stride))(pre_read)
-            pre_read = pre_read.reshape(rows, -1, pre_read.shape[-1])
-            post_read = post_read.astype(jnp.float32).reshape(
-                rows, -1, s_out.shape[-1])
+        with jax.named_scope(tracing.TIMING):
+            pre_read = rule.kernel_readout(pre_state, packed=packed)
+            post_read = rule.kernel_readout(post_state, packed=packed)
+            if pre_read.ndim == 1:
+                # per-neuron word readout: im2col the (M, K) uint8 words once
+                im2col_w = im2col_words_2d if kind == "conv2d" else im2col_words_1d
+                pre_read = im2col_w(pre_read.reshape((B,) + tuple(in_shape)),
+                                    kernel, stride)
+                pre_read = pre_read.reshape(-1, pre_read.shape[-1])  # (M, K)
+                post_read = post_read.reshape(-1, s_out.shape[-1])   # (M, C)
+            else:
+                # dense row layout: (rows, M, ·) float32 patches
+                im2col = im2col_2d if kind == "conv2d" else im2col_1d
+                rows = pre_read.shape[0]
+                pre_read = pre_read.astype(jnp.float32)
+                pre_read = pre_read.reshape((rows, B) + tuple(in_shape))
+                pre_read = jax.vmap(
+                    lambda p: im2col(p, kernel, stride))(pre_read)
+                pre_read = pre_read.reshape(rows, -1, pre_read.shape[-1])
+                post_read = post_read.astype(jnp.float32).reshape(
+                    rows, -1, s_out.shape[-1])
         pre_patches = patches.reshape(-1, patches.shape[-1])         # (M, K)
         post_spikes = s_out.reshape(-1, s_out.shape[-1])             # (M, C)
         if self.sparse:

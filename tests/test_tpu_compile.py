@@ -15,6 +15,7 @@ back without one.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -23,6 +24,7 @@ import pytest
 from jax.sharding import AxisType, Mesh, NamedSharding, SingleDeviceSharding
 from jax.sharding import PartitionSpec as P
 
+from repro import tracing
 from repro.core.engine import EngineConfig, engine_step, init_engine
 from repro.core.engine_sharded import make_sharded_engine_step
 from repro.kernels.itp_counter.kernel import counter_stdp_update
@@ -157,6 +159,28 @@ def test_run_snn_compiles_with_kernel(one_chip, net):
     raster = _spec(one_chip, (t_steps, batch, n_in), jnp.uint8)
     text = snn.run_snn.lower(state, raster, cfg, train=True).compile().as_text()
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("train", [True, False], ids=["train", "eval"])
+@pytest.mark.parametrize("net", ["2layer-snn", "6layer-dcsnn"])
+def test_run_snn_scopes_survive_the_tpu_compiler(one_chip, net, train):
+    """The device scopes in the chip's optimised text, and every kernel
+    custom call named after its entry point, under ``stdp.update``."""
+    cfg = snn.PAPER_NETWORKS[net]("itp", backend="fused")
+    batch, t_steps = 16, 30
+    state = _specs(one_chip, snn.init_snn(jax.random.PRNGKey(0), cfg, batch))
+    raster = _spec(one_chip, (t_steps, batch, 784), jnp.uint8)
+    text = snn.run_snn.lower(state, raster, cfg, train=train).compile().as_text()
+    scopes = {tracing.FORWARD, tracing.TIMING} | ({tracing.UPDATE} if train else set())
+    for scope in tracing.DEVICE_SCOPES:
+        assert (f"/{scope}/" in text) == (scope in scopes), scope
+    calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    assert bool(calls) == train
+    entry = "itp_stdp_update_packed" if net == "2layer-snn" else "itp_stdp_conv_delta_packed"
+    assert any(f"%{entry}" in line for line in calls) == train
+    for line in calls:
+        assert f"/{tracing.UPDATE}/" in line
+        assert re.search(r"%(itp_stdp_update_packed|itp_stdp_conv_delta_packed)[.\d]* = ", line)
 
 
 @pytest.mark.parametrize("rule", ["itp", "itp_nocomp", "exact", "linear", "imstdp"])
