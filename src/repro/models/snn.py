@@ -31,7 +31,8 @@ exactly the paper's equivalence claim.
 from __future__ import annotations
 
 import dataclasses
-from functools import partial
+import math
+from functools import lru_cache, partial
 from typing import Any, NamedTuple
 
 import jax
@@ -267,32 +268,40 @@ def _fan_in(spec: SNNLayerSpec, in_shape: tuple) -> int:
     return 0
 
 
-@partial(annotate_function, name=tracing.INIT_SNN)
-def init_snn(key: jax.Array, cfg: SNNConfig, batch: int) -> SNNState:
-    shapes = _layer_shapes(cfg)
-    weights, states = [], []
+def fresh_layers(cfg: SNNConfig, batch: int) -> tuple:
+    """Each layer's state at the start of a raster: fresh neurons, the
+    rule's empty timing state for both sides, zero θ (pool layers hold
+    ``None``).  A function of ``(cfg, batch)`` alone: no PRNG, no weights,
+    and the shape products come from Python, so nothing is read back from
+    the device."""
+    rule = cfg.learning_rule()
+    states = []
     in_shape = tuple(cfg.input_shape)
-    for spec, out_shape in zip(cfg.layers, shapes):
+    for spec, out_shape in zip(cfg.layers, _layer_shapes(cfg)):
         if spec.kind.startswith("pool"):
             states.append(LayerState(None, None, None))
         else:
-            key, sub = jax.random.split(key)
-            fi = _fan_in(spec, in_shape)
-            w = jax.random.uniform(sub, (fi, spec.out_features),
-                                   minval=0.2, maxval=0.8)
-            weights.append(w.astype(jnp.float32))
-            rule = cfg.learning_rule()
-            with TraceAnnotation(tracing.HOST_SYNC):
-                n_pre = batch * int(jnp.prod(jnp.asarray(in_shape)))
-                n_post = batch * int(jnp.prod(jnp.asarray(out_shape)))
             states.append(LayerState(
                 neurons=_neuron_init(cfg, (batch,) + out_shape),
-                pre_hist=rule.init_state(n_pre, cfg.depth),
-                post_hist=rule.init_state(n_post, cfg.depth),
+                pre_hist=rule.init_state(batch * math.prod(in_shape), cfg.depth),
+                post_hist=rule.init_state(batch * math.prod(out_shape), cfg.depth),
                 theta=jnp.zeros((spec.out_features,), jnp.float32),
             ))
         in_shape = out_shape
-    return SNNState(weights=tuple(weights), layers=tuple(states))
+    return tuple(states)
+
+
+@partial(annotate_function, name=tracing.INIT_SNN)
+def init_snn(key: jax.Array, cfg: SNNConfig, batch: int) -> SNNState:
+    in_shapes = [tuple(cfg.input_shape)] + _layer_shapes(cfg)[:-1]
+    weights = []
+    for spec, in_shape in zip(cfg.layers, in_shapes):
+        if not spec.kind.startswith("pool"):
+            key, sub = jax.random.split(key)
+            w = jax.random.uniform(sub, (_fan_in(spec, in_shape), spec.out_features),
+                                   minval=0.2, maxval=0.8)
+            weights.append(w.astype(jnp.float32))
+    return SNNState(weights=tuple(weights), layers=fresh_layers(cfg, batch))
 
 
 def _quantise(w: jax.Array, cfg: SNNConfig) -> jax.Array:
@@ -473,15 +482,27 @@ def run_snn(state: SNNState, raster: jax.Array, cfg: SNNConfig,
     return state, outs.sum(axis=0)
 
 
+@lru_cache(maxsize=8)
+def _reset_layers(cfg: SNNConfig, batch: int) -> tuple:
+    # One immutable fresh state per (cfg, batch), shared by every reset.
+    # Sound because nothing donates a state's buffers (run_snn does not), so
+    # the arrays here are never deleted under the cache.  Built eagerly even
+    # when the first reset runs under a trace, so the cache holds no tracers.
+    with TraceAnnotation(tracing.FRESH_STATE), jax.ensure_compile_time_eval():
+        return fresh_layers(cfg, batch)
+
+
 @partial(annotate_function, name=tracing.RESET_DYNAMICS)
 def reset_dynamics(state: SNNState, cfg: SNNConfig, batch: int) -> SNNState:
     """Zero neuron states + histories between samples; keep learned weights
     AND the adaptive thresholds θ — homeostasis is the slow variable that
-    must integrate firing rates across samples, not within one raster."""
-    fresh = init_snn(jax.random.PRNGKey(0), cfg, batch)
+    must integrate firing rates across samples, not within one raster.
+
+    After the first call for a ``(cfg, batch)`` this launches nothing on
+    the device and reads nothing back from it."""
     layers = tuple(
         f._replace(theta=old.theta) if old.theta is not None else f
-        for f, old in zip(fresh.layers, state.layers))
+        for f, old in zip(_reset_layers(cfg, batch), state.layers))
     return SNNState(weights=state.weights, layers=layers)
 
 

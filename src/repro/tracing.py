@@ -11,8 +11,8 @@ from __future__ import annotations
 
 # host spans -----------------------------------------------------------------
 RESET_DYNAMICS = "snn.reset_dynamics"    # the whole between-raster reset
-INIT_SNN = "snn.init_snn"                # state (re)construction
-HOST_SYNC = "snn.host_sync"              # a blocking device-to-host read in init_snn
+INIT_SNN = "snn.init_snn"                # weight draw + fresh state, at set-up
+FRESH_STATE = "snn.fresh_state"          # reset's cached fresh state built, once per (cfg, batch)
 SAMPLE = "pipeline.sample"               # the sampler call of spike_stream
 ENCODE = "pipeline.encode"               # min-max + Bernoulli rate coding
 PREFETCH_PUT = "pipeline.prefetch.put"   # device_put plus enqueue

@@ -92,6 +92,65 @@ def test_quantised_weights_on_grid(key):
     np.testing.assert_allclose(scaled, np.round(scaled), atol=1e-4)
 
 
+def _assert_same_leaves(a, b):
+    """Equal leaf for leaf: tree structure, dtypes, shapes, values."""
+    la, ta = jax.tree.flatten(a)
+    lb, tb = jax.tree.flatten(b)
+    assert ta == tb
+    for x, y in zip(la, lb):
+        assert x.dtype == y.dtype and x.shape == y.shape
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
+@pytest.mark.parametrize("net,rule", [
+    ("2layer-snn", "itp"), ("6layer-dcsnn", "itp"), ("2layer-snn", "exact")])
+def test_reset_dynamics_equals_a_fresh_init(key, net, rule):
+    """The reset's layers are a fresh ``init_snn``'s, leaf for leaf, but
+    for θ; weights and θ are the state's own, also after a ``run_snn``
+    between two resets."""
+    cfg = snn.PAPER_NETWORKS[net](rule, theta_plus=0.05)
+    B = 2
+    fresh = snn.init_snn(jax.random.PRNGKey(9), cfg, B).layers
+    if rule == "exact":   # counters start saturated: no spike in the window
+        np.testing.assert_array_equal(np.asarray(fresh[0].pre_hist), cfg.depth)
+    st = snn.init_snn(key, cfg, B)
+    x, _ = synthetic_digits(key, B)
+    st1 = snn.reset_dynamics(st, cfg, B)
+    st2, _ = snn.run_snn(st1, encode_batch(key, x, 4), cfg, train=True)
+    st3 = snn.reset_dynamics(st2, cfg, B)
+    assert float(jnp.abs(st2.layers[0].theta).max()) > 0.0
+    for before, after in ((st, st1), (st2, st3)):
+        assert all(w is v for w, v in zip(after.weights, before.weights))
+        kept = tuple(f._replace(theta=b.theta) if b.theta is not None else f
+                     for f, b in zip(fresh, before.layers))
+        _assert_same_leaves(after.layers, kept)
+
+
+def test_reset_dynamics_after_the_first_moves_nothing(key):
+    """Past the first call for a ``(cfg, batch)`` the reset hands back the
+    same fresh arrays: no transfer, nothing built on the device."""
+    cfg = snn.fmnist_dcsnn("itp")
+    B = 2
+    st = snn.reset_dynamics(snn.init_snn(key, cfg, B), cfg, B)
+    with jax.transfer_guard("disallow"):
+        st2 = snn.reset_dynamics(st, cfg, B)
+    for a, b in zip(jax.tree.leaves(st2), jax.tree.leaves(st)):
+        assert a is b
+
+
+def test_reset_dynamics_under_jit_caches_no_tracers(key):
+    cfg = snn.mnist_2layer("itp", theta_plus=0.05)
+    B = 3
+    st = snn.init_snn(key, cfg, B)
+    snn._reset_layers.cache_clear()
+    traced = jax.jit(lambda s: snn.reset_dynamics(s, cfg, B))(st)
+    eager = snn.reset_dynamics(st, cfg, B)
+    assert snn._reset_layers.cache_info().misses == 1
+    assert not any(isinstance(leaf, jax.core.Tracer)
+                   for leaf in jax.tree.leaves(snn._reset_layers(cfg, B)))
+    _assert_same_leaves(traced, eager)
+
+
 @pytest.mark.slow
 def test_learning_beats_chance(key):
     """End-to-end protocol at tiny scale: STDP features + ridge readout

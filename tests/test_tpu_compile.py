@@ -150,8 +150,7 @@ def test_run_snn_compiles_with_kernel(one_chip, net):
     """The whole training scan at batch 16, 30 steps, on ``fused``."""
     cfg = snn.PAPER_NETWORKS[net]("itp", backend="fused")
     batch, t_steps = 16, 30
-    # init_snn sizes its histories with concrete values, so build it on the
-    # host and keep only the shapes
+    # build the state on the host and keep only the shapes
     state = _specs(one_chip, snn.init_snn(jax.random.PRNGKey(0), cfg, batch))
     n_in = 1
     for d in cfg.input_shape:

@@ -55,17 +55,25 @@ def _threads_with(lines, name):
 
 
 def test_reset_dynamics_spans_nest_on_one_thread(traced):
+    """The reset's fresh state is built once per ``(cfg, batch)``, inside
+    the first reset; the second builds nothing and reads nothing back."""
     cfg = snn.fmnist_dcsnn("itp")
     state = snn.init_snn(jax.random.PRNGKey(0), cfg, 2)
-    lines = traced(lambda: jax.block_until_ready(snn.reset_dynamics(state, cfg, 2)))
+    snn._reset_layers.cache_clear()
+
+    def two_resets():
+        first = snn.reset_dynamics(state, cfg, 2)
+        jax.block_until_ready(snn.reset_dynamics(first, cfg, 2))
+
+    lines = traced(two_resets)
     (i,) = _threads_with(lines, tracing.RESET_DYNAMICS)
-    (reset,) = _named(lines[i], tracing.RESET_DYNAMICS)
-    (init,) = _named(lines[i], tracing.INIT_SNN)
-    syncs = _named(lines[i], tracing.HOST_SYNC)
-    assert _inside(init, reset)
-    # one blocking read pair per learnable layer (conv, conv, fc)
-    assert len(syncs) == 3 and all(_inside(s, init) for s in syncs)
-    assert _threads_with(lines, tracing.HOST_SYNC) == {i}
+    first, second = sorted(_named(lines[i], tracing.RESET_DYNAMICS), key=lambda e: e[1])
+    (build,) = _named(lines[i], tracing.FRESH_STATE)
+    assert _inside(build, first) and not _inside(build, second)
+    assert _threads_with(lines, tracing.FRESH_STATE) == {i}
+    # a reset neither draws weights nor reads anything back
+    assert not _threads_with(lines, tracing.INIT_SNN)
+    assert not _threads_with(lines, "snn.host_sync")
 
 
 def test_prefetcher_spans_sit_on_the_producer_thread(traced):
