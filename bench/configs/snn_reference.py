@@ -23,7 +23,8 @@ order:
   * the step's input and output spikes enter the layer's depth-``depth``
     spike registers (k = 0 is the newest step).
 
-Pooling is an OR over ``pool x pool`` windows.
+Pooling is an OR over windows of ``pool`` steps (1-D) or ``pool x pool``
+pixels (2-D), the remainder of the input left out.
 """
 from __future__ import annotations
 
@@ -95,7 +96,7 @@ class RefConfig:
         lif, iz, sp = c["lif"], c["izhi"], c["stdp"]
         prec = c["precision"]
         upd = prec["fc_update"]
-        if any(l[0] == "conv2d" for l in layers) and prec["conv_update"] != upd:
+        if any(l[0] in ("conv1d", "conv2d") for l in layers) and prec["conv_update"] != upd:
             raise ValueError("one update precision for all layers is modelled")
         return cls(
             input_shape=tuple(c["input_shape"]), layers=layers, neuron=c["neuron"],
@@ -112,6 +113,10 @@ class RefConfig:
 # shapes and initial weights
 # ---------------------------------------------------------------------------
 
+def is_pool(kind: str) -> bool:
+    return kind in ("pool1d", "pool2d")
+
+
 def layer_shapes(rc: RefConfig) -> list[tuple]:
     """(input shape, output shape) of every layer, batch excluded."""
     shape, out = rc.input_shape, []
@@ -121,9 +126,15 @@ def layer_shapes(rc: RefConfig) -> list[tuple]:
         elif kind == "conv2d":
             h, w, _ = shape
             new = ((h - k) // s + 1, (w - k) // s + 1, c_out)
+        elif kind == "conv1d":
+            length, _ = shape
+            new = ((length - k) // s + 1, c_out)
         elif kind == "pool2d":
             h, w, c = shape
             new = (h // p, w // p, c)
+        elif kind == "pool1d":
+            length, c = shape
+            new = (length // p, c)
         else:
             raise ValueError(f"layer kind {kind!r} is not modelled")
         out.append((shape, new))
@@ -134,6 +145,8 @@ def layer_shapes(rc: RefConfig) -> list[tuple]:
 def fan_in(kind: str, k: int, in_shape: tuple) -> int:
     if kind == "fc":
         return math.prod(in_shape)
+    if kind == "conv1d":
+        return k * in_shape[-1]
     return k * k * in_shape[-1]
 
 
@@ -141,7 +154,7 @@ def init_weights(key: jax.Array, rc: RefConfig, low: float, high: float) -> tupl
     """U(low, high) weights per learnable layer, one key split per layer."""
     ws = []
     for (kind, c_out, k, _, _), (in_shape, _) in zip(rc.layers, layer_shapes(rc)):
-        if kind == "pool2d":
+        if is_pool(kind):
             continue
         key, sub = jax.random.split(key)
         ws.append(jax.random.uniform(sub, (fan_in(kind, k, in_shape), c_out),
@@ -162,9 +175,19 @@ def patches_2d(x: jax.Array, k: int, s: int) -> jax.Array:
     return jnp.stack(cols, axis=3).reshape(B, ho * wo, k * k * C)
 
 
+def patches_1d(x: jax.Array, k: int, s: int) -> jax.Array:
+    """(B, L, C) -> (B, Lo, k*C), features in (k, c) order."""
+    B, L, C = x.shape
+    lo = (L - k) // s + 1
+    cols = [x[:, i:i + s * (lo - 1) + 1:s, :] for i in range(k)]
+    return jnp.stack(cols, axis=2).reshape(B, lo, k * C)
+
+
 def _patches(kind: str, x: jax.Array, k: int, s: int) -> jax.Array:
     if kind == "fc":
         return x.reshape(x.shape[0], 1, -1)
+    if kind == "conv1d":
+        return patches_1d(x, k, s)
     return patches_2d(x, k, s)
 
 
@@ -250,7 +273,11 @@ def learnable_step(rc: RefConfig, spec: tuple, w: jax.Array, ls: dict,
     return w, ls, spikes
 
 
-def _pool(x: jax.Array, p: int) -> jax.Array:
+def _pool(kind: str, x: jax.Array, p: int) -> jax.Array:
+    if kind == "pool1d":
+        B, L, C = x.shape
+        x = x[:, :L // p * p].astype(jnp.float32)
+        return x.reshape(B, L // p, p, C).max(axis=2) > 0.5
     B, H, W, C = x.shape
     x = x[:, :H // p * p, :W // p * p].astype(jnp.float32)
     return x.reshape(B, H // p, p, W // p, p, C).max(axis=(2, 4)) > 0.5
@@ -260,7 +287,7 @@ def fresh_state(rc: RefConfig, batch: int) -> list:
     """Per learnable layer: rest-state neurons, empty registers, no spikes."""
     out = []
     for (kind, *_), (in_shape, o_shape) in zip(rc.layers, layer_shapes(rc)):
-        if kind == "pool2d":
+        if is_pool(kind):
             continue
         out.append({
             "neurons": _fresh_neurons(rc, (batch,) + o_shape),
@@ -288,8 +315,8 @@ def run_raster(rc: RefConfig, weights: tuple, raster: jax.Array, *, train: bool)
         new_w, new_l = [], []
         s, li = xt, 0
         for spec in rc.layers:
-            if spec[0] == "pool2d":
-                s = _pool(s, spec[4])
+            if is_pool(spec[0]):
+                s = _pool(spec[0], s, spec[4])
                 continue
             w, ls, s = learnable_step(rc, spec, ws[li], layers[li], s, train)
             new_w.append(w)

@@ -27,6 +27,9 @@ def layers(c: dict, batch: int) -> list[dict]:
             p = spec["pool"]
             shape = (shape[0] // p, shape[1] // p, shape[2])
             continue
+        if kind == "pool1d":
+            shape = (shape[0] // spec["pool"], shape[1])
+            continue
         n_in = math.prod(shape)
         if kind == "fc":
             P, K, new = 1, n_in, (spec["out_features"],)
@@ -34,6 +37,10 @@ def layers(c: dict, batch: int) -> list[dict]:
             k, s = spec["kernel"], spec.get("stride", 1)
             ho, wo = (shape[0] - k) // s + 1, (shape[1] - k) // s + 1
             P, K, new = ho * wo, k * k * shape[2], (ho, wo, spec["out_features"])
+        elif kind == "conv1d":
+            k, s = spec["kernel"], spec.get("stride", 1)
+            lo = (shape[0] - k) // s + 1
+            P, K, new = lo, k * shape[1], (lo, spec["out_features"])
         else:
             raise ValueError(f"no cost model for layer kind {kind!r}")
         out.append({"kind": kind, "B": batch, "P": P, "K": K,

@@ -73,12 +73,18 @@ def seed_key(seed: int) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 def program_config(c: dict, **override) -> snn.SNNConfig:
-    """The network maker's config with the file's settings, each verified.
+    """The config of the maker the file names (one of the program's
+    paper-network functions, by its function name) with the file's
+    settings, each verified.
 
     Every setting the file states must be what the program runs; a
     maker default that drifts from the file stops the run.
     """
-    maker = snn.PAPER_NETWORKS[c["name"]]
+    makers = {f.__name__: f for f in snn.PAPER_NETWORKS.values()}
+    if c["maker"] not in makers:
+        raise SystemExit(f"{c['name']}: the program has no network maker {c['maker']!r}; "
+                         f"known: {sorted(makers)}")
+    maker = makers[c["maker"]]
     kw = {"backend": c["backend"], "packed_history": c["packed_history"],
           "quantise": c["quantise"]}
     kw.update(override)
@@ -119,9 +125,20 @@ def forward_precision(c: dict):
 # ---------------------------------------------------------------------------
 
 def make_pool(key: jax.Array, c: dict, n: int) -> tuple[jax.Array, jax.Array]:
+    """``n`` samples of the file's sampler, each of the file's input shape
+    (a unit channel the sampler leaves out aside); a sampler that drifts
+    from the file stops the run."""
     gen, _ = cli.sampler_for(c["data"]["sampler"])
     x, y = jax.jit(gen, static_argnums=1)(key, n)
+    if _unit_dims_dropped(x.shape[1:]) != _unit_dims_dropped(c["input_shape"]):
+        raise SystemExit(f"{c['name']}: the sampler {c['data']['sampler']!r} gives samples "
+                         f"of shape {tuple(x.shape[1:])}, file states input_shape "
+                         f"{tuple(c['input_shape'])}")
     return x, y
+
+
+def _unit_dims_dropped(shape) -> tuple:
+    return tuple(d for d in shape if d != 1)
 
 
 def pool_indices(k: jax.Array, n: int, size: int) -> jax.Array:
