@@ -319,9 +319,10 @@ class UpdatePlan:
             if pre_read.ndim == 1:
                 # per-neuron word readout: im2col the (M, K) uint8 words once
                 im2col_w = im2col_words_2d if kind == "conv2d" else im2col_words_1d
-                pre_read = im2col_w(pre_read.reshape((B,) + tuple(in_shape)),
-                                    kernel, stride)
-                pre_read = pre_read.reshape(-1, pre_read.shape[-1])  # (M, K)
+                with jax.named_scope(tracing.WINDOW):
+                    pre_read = im2col_w(pre_read.reshape((B,) + tuple(in_shape)),
+                                        kernel, stride)
+                    pre_read = pre_read.reshape(-1, pre_read.shape[-1])  # (M, K)
                 post_read = post_read.reshape(-1, s_out.shape[-1])   # (M, C)
             else:
                 # dense row layout: (rows, M, ·) float32 patches

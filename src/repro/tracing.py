@@ -25,3 +25,6 @@ TIMING = "stdp.timing"       # history pushes and every kernel readout of them
 UPDATE = "stdp.update"       # update kernel, batch sum, clip, quantise
 
 DEVICE_SCOPES = (FORWARD, TIMING, UPDATE)
+# nested in TIMING and not one of the three, so its ops still count there;
+# it lets the conv readout's gather read apart from the word pack before it
+WINDOW = "stdp.window"       # im2col gather of the packed history words into patch rows

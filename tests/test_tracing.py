@@ -6,6 +6,7 @@ host thread is one line of the host plane.  Device scopes are read from the
 interpret mode here; ``test_tpu_compile.py`` checks the chip's text), where
 every nested jit is inlined, so each op carries its whole name stack.
 """
+import functools
 import glob
 import re
 
@@ -19,7 +20,7 @@ from repro.data import pipeline, synthetic_digits
 from repro.models import snn
 
 OP_NAME = re.compile(r'op_name="([^"]*)"')
-# the pallas_call names of the packed kernels the two paper networks run
+# the pallas_call names of the packed kernels the paper networks run
 KERNELS = {"itp_stdp_update_packed", "itp_stdp_conv_delta_packed"}
 
 
@@ -96,6 +97,7 @@ def test_prefetcher_spans_sit_on_the_producer_thread(traced):
     assert _threads_with(lines, tracing.PREFETCH_WAIT_ITEM) <= {consumer}
 
 
+@functools.lru_cache(maxsize=None)
 def _scope_paths(net: str, train: bool) -> list[set]:
     cfg = snn.PAPER_NETWORKS[net]("itp", backend="fused_interpret")
     state = snn.init_snn(jax.random.PRNGKey(0), cfg, 2)
@@ -108,7 +110,7 @@ def _scope_paths(net: str, train: bool) -> list[set]:
 
 
 @pytest.mark.parametrize("train", [True, False], ids=["train", "eval"])
-@pytest.mark.parametrize("net", ["2layer-snn", "6layer-dcsnn"])
+@pytest.mark.parametrize("net", ["2layer-snn", "6layer-dcsnn", "5layer-csnn"])
 def test_run_snn_ops_carry_the_device_scopes(net, train):
     paths = _scope_paths(net, train)
     seen = set().union(*paths) & set(tracing.DEVICE_SCOPES)
@@ -117,3 +119,14 @@ def test_run_snn_ops_carry_the_device_scopes(net, train):
     kernels = [p for p in paths if p & KERNELS]
     assert bool(kernels) == train
     assert all(tracing.UPDATE in p for p in kernels)
+
+
+@pytest.mark.parametrize("train", [True, False], ids=["train", "eval"])
+@pytest.mark.parametrize("net", ["2layer-snn", "6layer-dcsnn", "5layer-csnn"])
+def test_window_scope_marks_the_conv_gather_of_words(net, train):
+    """``stdp.window`` is on the im2col gather of the packed words, which
+    only a conv layer's update has, and always nested in ``stdp.timing``."""
+    window = [p for p in _scope_paths(net, train) if tracing.WINDOW in p]
+    assert bool(window) == (train and net != "2layer-snn")
+    assert all(tracing.TIMING in p for p in window)
+    assert tracing.WINDOW not in tracing.DEVICE_SCOPES
