@@ -37,7 +37,21 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.itp_stdp.kernel import first_one_mask
+
+def first_one_mask(bits: jax.Array) -> jax.Array:
+    """Fig. 11 MSB priority encode on {0,1} planes stacked along axis 0.
+
+    Keeps only the first '1' scanning most-recent-first — exactly
+    ``bits * (cumsum(bits, axis=0) == 1)`` on {0,1} — as an unrolled scan
+    over the static depth (≤ 8) with a running "seen" plane.
+    """
+    seen = jnp.zeros_like(bits[0:1])
+    rows = []
+    for k in range(bits.shape[0]):
+        row = bits[k:k + 1] * (1.0 - seen)
+        rows.append(row)
+        seen = seen + row
+    return jnp.concatenate(rows, axis=0)
 
 
 def _unpack_bits(words: jax.Array, depth: int) -> jax.Array:

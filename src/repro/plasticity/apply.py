@@ -226,36 +226,33 @@ class UpdatePlan:
 
     def _batched_readouts(self, pre_state: Any, post_state: Any,
                           batch: int) -> tuple[jax.Array, jax.Array]:
-        """Per-sample kernel readout views for the fc paths.
+        """Batch-major kernel readout views for the fc paths.
 
         Word readouts ((B·n,) uint8 — packed register / counter words)
-        reshape to ``(B, n)``; row readouts ((rows, B·n)) to per-sample
-        ``(B, rows, n)`` views (row count is rule-specific — ``depth``
+        reshape to ``(B, n)``; row readouts ((rows, B·n)) to
+        ``(rows, B, n)`` (row count is rule-specific — ``depth``
         bitplanes for the history rules, one counter row, history+trace
         rows for composite-state rules).
         """
         pre_read = self.rule.kernel_readout(pre_state, packed=self.packed)
         post_read = self.rule.kernel_readout(post_state, packed=self.packed)
         if pre_read.ndim == 1:
-            pre_read = pre_read.reshape(batch, -1)
-            post_read = post_read.reshape(batch, -1)
-        else:
-            pre_read = pre_read.reshape(
-                pre_read.shape[0], batch, -1).transpose(1, 0, 2)
-            post_read = post_read.reshape(
-                post_read.shape[0], batch, -1).transpose(1, 0, 2)
-        return pre_read, post_read
+            return pre_read.reshape(batch, -1), post_read.reshape(batch, -1)
+        return (pre_read.reshape(pre_read.shape[0], batch, -1),
+                post_read.reshape(post_read.shape[0], batch, -1))
 
     def fc_delta(self, pre_state: Any, post_state: Any, s_in: jax.Array,
                  s_out: jax.Array) -> jax.Array:
         """Batch-summed raw ``(fan_in, n_out)`` Δw for an fc layer.
 
         The fc layer is the engine's dense synapse matrix replicated over
-        the batch: the fused and sparse backends vmap the rule's
-        per-sample delta hook and accumulate; the reference backend is
-        the einsum form of the same pair-gated rank-1 update (P = 1
-        special case of the conv patch formula).  Raw delta — the layer
-        owns eta / B normalisation / clip / quantise.
+        the batch: the fused backends hand the whole batch to the rule's
+        delta hook once (the itp kernel contracts the batch rows into one
+        Δw tile), the sparse backend vmaps its per-sample event hook and
+        accumulates; the reference backend is the einsum form of the same
+        pair-gated rank-1 update (P = 1 special case of the conv patch
+        formula).  Raw delta — the layer owns eta / B normalisation /
+        clip / quantise.
         """
         B = s_in.shape[0]
         pre = s_in.reshape(B, -1)                       # (B, fan_in)
@@ -280,19 +277,21 @@ class UpdatePlan:
             return dw_ltp - dw_ltd
         with jax.named_scope(tracing.TIMING):
             pre_read, post_read = self._batched_readouts(pre_state, post_state, B)
-        if self.sparse:
-            def one(p, q, pr, qr):
-                return self.rule.sparse_delta_from_readout(
-                    p, q, pr, qr, self.stdp, depth=self.depth,
-                    pairing=self.pairing, compensate=self.compensate,
-                    max_events=self.max_events)
-        else:
-            def one(p, q, pr, qr):
-                return self.rule.fused_delta_from_readout(
-                    p, q, pr, qr, self.stdp, depth=self.depth,
-                    pairing=self.pairing, compensate=self.compensate,
-                    interpret=self.interpret)
-        return jax.vmap(one)(pre, post, pre_read, post_read).sum(axis=0)
+        if not self.sparse:
+            return self.rule.fused_delta_from_readout(
+                pre, post, pre_read, post_read, self.stdp, depth=self.depth,
+                pairing=self.pairing, compensate=self.compensate,
+                interpret=self.interpret)
+
+        def one(p, q, pr, qr):
+            return self.rule.sparse_delta_from_readout(
+                p, q, pr, qr, self.stdp, depth=self.depth,
+                pairing=self.pairing, compensate=self.compensate,
+                max_events=self.max_events)
+
+        row_axis = 0 if pre_read.ndim == 2 else 1       # the batch axis
+        return jax.vmap(one, in_axes=(0, 0, row_axis, row_axis))(
+            pre, post, pre_read, post_read).sum(axis=0)
 
     def conv_delta(self, pre_state: Any, post_state: Any,
                    patches: jax.Array, s_out: jax.Array, *,

@@ -193,8 +193,12 @@ class LearningRule(abc.ABC):
         compensate: bool = True,
         interpret: bool = False,
     ) -> jax.Array:
-        """Raw fused ``(n_pre, n_post)`` Δw (no eta/clip) — the batched
-        SNN fc layers vmap this over samples and accumulate."""
+        """Raw fused ``(n_pre, n_post)`` Δw (no eta/clip), summed over a batch.
+
+        The SNN fc layers call this once per step with the whole batch:
+        spikes ``(B, n)`` and :meth:`kernel_readout` views with the batch
+        before the neuron axis — words ``(B, n)``, rows ``(rows, B, n)``
+        (the conv hooks' patch layout with one position per sample)."""
         raise NotImplementedError(f"rule {self.name!r} has no fused kernel")
 
     def conv_delta_from_readout(

@@ -160,8 +160,9 @@ class HistoryRule(LearningRule):
     ) -> jax.Array:
         from repro.kernels.itp_stdp.ops import synapse_delta, synapse_delta_packed
 
+        # one kernel call contracts the whole batch into one Δw tile
         kw = dict(pairing=pairing, compensate=compensate, interpret=interpret)
-        if pre_read.ndim == 1:  # packed uint8 register words
+        if pre_read.ndim == 2:  # (B, n) packed words (bitplanes are (depth, B, n))
             return synapse_delta_packed(
                 pre_spike, post_spike, pre_read, post_read, p, depth=depth, **kw
             )
@@ -442,16 +443,14 @@ class CounterRule(LearningRule):
 
         self.check_pairing(pairing)
         del compensate
-        return counter_synapse_delta(
-            pre_spike,
-            post_spike,
-            pre_read,
-            post_read,
-            p,
-            depth=depth,
-            window=self.window,
-            interpret=interpret,
-        )
+
+        # the counter kernel takes one sample: map it over the (B, n) words
+        def one(ps, qs, pw, qw):
+            return counter_synapse_delta(
+                ps, qs, pw, qw, p, depth=depth, window=self.window, interpret=interpret
+            )
+
+        return jax.vmap(one)(pre_spike, post_spike, pre_read, post_read).sum(axis=0)
 
     def conv_delta_from_readout(
         self,

@@ -106,6 +106,33 @@ def test_packed_snn_fc_trajectory_bit_identical_to_unpacked(key):
     np.testing.assert_array_equal(np.asarray(counts_p), np.asarray(counts_u))
 
 
+def _pallas_calls(jaxpr):
+    """Every ``pallas_call`` equation in ``jaxpr`` and its sub-jaxprs."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(eqn)
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _pallas_calls(sub)
+    return found
+
+
+def test_fc_delta_is_one_batch_contracting_kernel_call(key):
+    """The fc update hands the whole batch to one kernel call whose only
+    output is the padded (K, C) Δw tile — no per-sample tiles to sum."""
+    from repro.plasticity.apply import make_plan
+    cfg = snn.mnist_2layer("itp", backend="fused_interpret")   # 784 -> 100
+    batch = 16
+    layer = snn.init_snn(key, cfg, batch).layers[0]
+    s_in = jax.random.bernoulli(key, 0.2, (batch, 784)).astype(np.float32)
+    s_out = jax.random.bernoulli(key, 0.2, (batch, 100)).astype(np.float32)
+    closed = jax.make_jaxpr(make_plan(cfg).fc_delta)(
+        layer.pre_hist, layer.post_hist, s_in, s_out)
+    (call,) = _pallas_calls(closed.jaxpr)
+    assert [v.aval.shape for v in call.outvars] == [(896, 128)]
+
+
 def test_depth_beyond_word_width_falls_back_to_unpacked(key):
     """depth > 8 exceeds the packed uint8 word; the fused path must keep
     running on the unpacked bitplane operands (previously-working configs

@@ -168,6 +168,61 @@ def test_engine_weight_update_packed_toggle_matches(key):
                                rtol=1e-5, atol=1e-6)
 
 
+@pytest.mark.parametrize("compensate", [True, False])
+@pytest.mark.parametrize("pairing", ["nearest", "all"])
+@pytest.mark.parametrize("n_pre,n_post", [(784, 100), (600, 128), (480, 64)])
+@pytest.mark.parametrize("batch", [1, 4, 16, 20])
+def test_batched_fc_kernels_equal_per_sample_sum(key, batch, n_pre, n_post,
+                                                 pairing, compensate):
+    """One kernel call contracts the batch: the packed and unpacked kernels
+    equal the per-sample sum of the oracle, and each other bit for bit.
+    B = 1 comes as 1-D arguments (the engine's call); B = 20 pads the batch
+    to sublane rows."""
+    from repro.core.history import unpack_words
+    from repro.kernels.itp_stdp.ops import (synapse_delta,
+                                            synapse_delta_packed,
+                                            weight_update_packed)
+    from repro.kernels.itp_stdp.ref import itp_stdp_update_ref
+    depth = 7
+    ks = jax.random.split(key, 5)
+    pre_s = jax.random.bernoulli(ks[0], 0.3, (batch, n_pre)).astype(jnp.float32)
+    post_s = jax.random.bernoulli(ks[1], 0.3, (batch, n_post)).astype(jnp.float32)
+    # depth-7 registers: the low word bit is never set
+    pre_w = (jax.random.randint(ks[2], (batch, n_pre), 0, 128) * 2).astype(jnp.uint8)
+    post_w = (jax.random.randint(ks[3], (batch, n_post), 0, 128) * 2).astype(jnp.uint8)
+    pre_b = jnp.moveaxis(unpack_words(pre_w, depth), -1, 0)     # (depth, B, n)
+    post_b = jnp.moveaxis(unpack_words(post_w, depth), -1, 0)
+    p = STDPParams()
+    ltp = p.a_plus * po2_weights(depth, p.tau_plus, compensate=compensate)
+    ltd = p.a_minus * po2_weights(depth, p.tau_minus, compensate=compensate)
+    want = sum(
+        itp_stdp_update_ref(jnp.zeros((n_pre, n_post)), pre_s[b], post_s[b],
+                            pre_b[:, b], post_b[:, b], ltp, ltd,
+                            nearest=pairing == "nearest", eta=1.0,
+                            w_min=-jnp.inf, w_max=jnp.inf)
+        for b in range(batch))
+    args = (pre_s, post_s, pre_w, post_w, pre_b, post_b)
+    if batch == 1:
+        args = tuple(a[..., 0, :] for a in args)
+    pre_s, post_s, pre_w, post_w, pre_b, post_b = args
+    kw = dict(pairing=pairing, compensate=compensate, interpret=True)
+    packed = synapse_delta_packed(pre_s, post_s, pre_w, post_w, p,
+                                  depth=depth, **kw)
+    unpacked = synapse_delta(pre_s, post_s, pre_b, post_b, p, **kw)
+    assert packed.shape == (n_pre, n_post)
+    np.testing.assert_array_equal(np.asarray(packed), np.asarray(unpacked))
+    np.testing.assert_allclose(np.asarray(packed), np.asarray(want),
+                               rtol=0, atol=1e-5)
+    # the clipped read-modify-write applies the summed Δw once
+    w = jax.random.uniform(ks[4], (n_pre, n_post))
+    got = weight_update_packed(w, pre_s, post_s, pre_w, post_w, p,
+                               depth=depth, eta=0.5, **kw)
+    np.testing.assert_allclose(np.asarray(got),
+                               np.clip(np.asarray(w) + 0.5 * np.asarray(want),
+                                       0.0, 1.0),
+                               rtol=0, atol=1e-5)
+
+
 def test_interpret_default_derives_from_host():
     """The ops wrappers' interpret default comes from the dispatch layer:
     on CPU it resolves to the interpreter (the only thing that runs), on an

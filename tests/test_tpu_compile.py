@@ -97,6 +97,32 @@ def test_dense_kernel_compiles_at_2layer_width(one_chip, nearest):
 
 
 @pytest.mark.parametrize("nearest", [True, False], ids=["nearest", "all_to_all"])
+def test_batched_dense_kernel_compiles_at_b256(one_chip, nearest):
+    """The fc update of a B = 256 training step: the batch rows are the
+    contracting axis of the kernel's two MXU dots, 128 rows per grid step."""
+    batch = 256
+
+    def fn(w, ps, qs, pw, qw, lt, ld):
+        return itp_stdp_update_packed(
+            w, ps, qs, pw, qw, lt, ld, depth=DEPTH, nearest=nearest, tile_pre=128,
+            tile_post=128, tile_batch=128,
+        )
+
+    s = one_chip
+    text = _compile_text(
+        fn,
+        _spec(s, (FC_PRE, FC_POST)),
+        _spec(s, (batch, FC_PRE)),
+        _spec(s, (batch, FC_POST)),
+        _spec(s, (batch, FC_PRE), jnp.uint8),
+        _spec(s, (batch, FC_POST), jnp.uint8),
+        _spec(s, (DEPTH,)),
+        _spec(s, (DEPTH,)),
+    )
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("nearest", [True, False], ids=["nearest", "all_to_all"])
 def test_conv_kernel_compiles_at_dcsnn_conv1(one_chip, nearest):
     def fn(pre, post, pw, qw, lt, ld):
         return itp_stdp_conv_delta_packed(pre, post, pw, qw, lt, ld, depth=DEPTH, nearest=nearest)
