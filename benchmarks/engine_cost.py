@@ -2,7 +2,7 @@
 measured learning-engine throughput.
 
 Silicon metrics (GHz, µm², pJ/SOP) do not transfer to a JAX repro
-(DESIGN.md §2); what does transfer is the *operation-count asymmetry* the
+(docs/architecture.md §7); what does transfer is the *operation-count asymmetry* the
 tables monetise.  Two parts:
 
 1. **Op/bit-count model** — arithmetic ops + storage bits per synaptic

@@ -4,7 +4,7 @@ paper's three networks.
 Protocol (identical across rules, so differences isolate the rule):
 unsupervised STDP feature learning → frozen features → ridge readout.
 Datasets are the synthetic stand-ins (MNIST & co. are not available
-offline — DESIGN.md §8); the claim under test is *parity* between
+offline — docs/architecture.md §7); the claim under test is *parity* between
 original STDP, ITP-STDP (comp.) and ITP-STDP (w/o comp.), which the paper
 reports as ≤ ~0.4 pp spread on MNIST and no systematic degradation."""
 from __future__ import annotations

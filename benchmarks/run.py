@@ -1,6 +1,6 @@
 """Benchmark orchestrator: ``PYTHONPATH=src python -m benchmarks.run``.
 
-One module per paper table/figure (DESIGN.md §7), registered in
+One module per paper table/figure, registered in
 ``MODULES`` — the single source the ``--only`` choices, the ``--list``
 output, and the dispatch loop all derive from, so the CLI surface cannot
 drift from what actually runs (CI smokes ``--list`` against the modules
@@ -22,7 +22,6 @@ it exercises):
                         throughput vs batch, bytes/session + sessions/GiB
                         of the packed-word plasticity cache, interleaved
                         bit-identity (gated in CI)
-    roofline          — §Roofline terms from the dry-run artifacts
     static_audit      — jaxpr contract audit fingerprint: per-cell
                         primitive counts of the traced rule × backend ×
                         layer-kind matrix (no execution; CI diffs it)
@@ -127,12 +126,6 @@ def _run_serve_cost(args):
                 r["isolation"]["interleaved_bit_identical"]}
 
 
-def _run_roofline(args):
-    from benchmarks import roofline
-    r = roofline.run(args.out)
-    return {"cells": len(r["rows"]), "missing": len(r["missing"])}
-
-
 def _run_static_audit(args):
     from benchmarks import static_audit
     r = static_audit.run(args.out, quick=args.quick)
@@ -152,7 +145,6 @@ MODULES = {
     "conv_cost": _run_conv_cost,
     "sparse_cost": _run_sparse_cost,
     "serve_cost": _run_serve_cost,
-    "roofline": _run_roofline,
     "static_audit": _run_static_audit,
 }
 

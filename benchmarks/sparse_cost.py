@@ -25,8 +25,9 @@ Two speedup columns per density cell:
 
 The crossover densities (modelled and measured, linear interpolation of
 the speedup-vs-density curve through 1.0) land in the JSON next to the
-roofline arithmetic-intensity ridge (``benchmarks/roofline.py``) — the
-target the sparse datapath's gather/scatter traffic is priced against.
+roofline arithmetic-intensity ridge of one v5e chip (``PEAK_FLOPS /
+HBM_BW``) — the target the sparse datapath's gather/scatter traffic is
+priced against.
 
 Merges a ``sparse`` section into the tracked repo-root BENCH_engine.json
 (``benchmarks/bench_io.py`` read-modify-write, never clobbering the
@@ -43,11 +44,13 @@ import os
 import jax
 
 from benchmarks.bench_io import update_bench_json
-from benchmarks.roofline import HBM_BW, PEAK_FLOPS
 from benchmarks.rule_cost import _time_fn
 from repro.core.engine import EngineConfig, init_engine, run_engine
 from repro.kernels.dispatch import default_fused_backend
 from repro.kernels.itp_sparse.events import event_cap
+
+PEAK_FLOPS = 197e12  # bf16 / chip (TPU v5e)
+HBM_BW = 819e9  # B/s / chip (TPU v5e)
 
 DENSITIES = (0.01, 0.02, 0.05, 0.1, 0.2, 0.4)
 QUICK_DENSITIES = (0.02, 0.2)

@@ -52,8 +52,8 @@ Its keywords have changed across jax releases (`auto=`/`check_rep=` on
 the old `jax.experimental.shard_map`, `axis_names=`/`check_vma=` on
 `jax.shard_map`).  `shard_map_compat` in repro/distributed/sharding.py
 is the single call site that knows them — every other reference to the
-raw name re-opens that break (train_step.py:129 shipped exactly that
-bug).""",
+raw name re-opens that break (a multi-pod step that called
+`jax.shard_map` directly shipped exactly that bug).""",
     "R2": """\
 R2: `repro.kernels.itp_*` packages are importable only by the plasticity
 rules and by kernel packages themselves.
@@ -97,8 +97,8 @@ R7: every module under src/repro must be statically reachable from an
 entry point (repro.launch.*, examples/, benchmarks/, tools/, tests/).
 Unreachable modules are dead code that still bit-rots against the moving
 APIs and silently escapes every test tier.  The tracked baseline lists
-the known orphans (e.g. the dynamically-imported LM arch configs) with a
-justification each; the list may only shrink.""",
+the known orphans (e.g. modules loaded only through importlib strings)
+with a justification each; the list may only shrink.""",
     "R8": """\
 R8: rule datapath hooks are called only inside repro/plasticity/.
 `kernel_readout` / `kernel_readout_axes` / `magnitudes_from_readout`,

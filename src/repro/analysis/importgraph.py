@@ -5,8 +5,8 @@ import edges from the entry-point surfaces: ``repro.launch.*`` (the CLI),
 plus every script/module under ``benchmarks/``, ``examples/``,
 ``tools/`` and ``tests/``.  Anything under ``src/`` not reached is an
 orphan finding keyed by *module name* (the allowlist records known
-orphans — e.g. the LM arch configs loaded via ``importlib`` strings —
-with a justification each).
+orphans, such as modules loaded only via ``importlib`` strings, with a
+justification each).
 
 Conservative choices: ``from pkg import name`` marks ``pkg`` and, when
 ``pkg.name`` is a known module, that module too; importing any module

@@ -1,4 +1,4 @@
-"""Distributed ITP-STDP learning engine (DESIGN.md §4.1).
+"""Distributed ITP-STDP learning engine (docs/architecture.md §8).
 
 Scales the learning engine from the paper's 4×4 prototype to layer-sized
 synapse matrices across a device mesh: the weight matrix shards 2-D over

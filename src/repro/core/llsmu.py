@@ -15,7 +15,7 @@ Note on eq. (7): as typeset, the δ≥1 branch lacks the ×2 radix correction
 (the true product lies in [2·2^(kx+ky), 4·2^(kx+ky)) there).  We implement
 the standard minimally-biased form  2^(kx+ky+1)·(δ + c/2), which is
 continuous with the δ<1 branch at δ=1 (both give 2^(kx+ky)(2+c)) and matches
-[32]; DESIGN.md records this as a presumed typo.
+[32]; docs/architecture.md §7 records this as a presumed typo.
 """
 from __future__ import annotations
 

@@ -1,10 +1,8 @@
-from repro.data.synthetic import (LMBatchSpec, host_shard, lm_batches,
-                                  synthetic_digits, synthetic_fashion,
-                                  synthetic_fault, zipf_tokens)
+from repro.data.synthetic import (synthetic_digits, synthetic_fashion,
+                                  synthetic_fault)
 from repro.data.pipeline import Prefetcher, encode_batch, spike_stream
 
 __all__ = [
-    "LMBatchSpec", "host_shard", "lm_batches", "synthetic_digits",
-    "synthetic_fashion", "synthetic_fault", "zipf_tokens",
+    "synthetic_digits", "synthetic_fashion", "synthetic_fault",
     "Prefetcher", "encode_batch", "spike_stream",
 ]

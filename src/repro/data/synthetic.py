@@ -1,18 +1,16 @@
 """Procedurally generated datasets standing in for the paper's three
-datasets (MNIST, Fashion-MNIST, motor rotor-fault) plus LM token streams.
+datasets (MNIST, Fashion-MNIST, motor rotor-fault).
 
-MNIST/F-MNIST/the fault dataset are not available offline (DESIGN.md §8);
-these generators produce class-structured data with the same shapes and
-dynamic range, so the *parity* experiments of Table II (exact STDP vs
-ITP-STDP ± compensation under one protocol) remain meaningful.
+MNIST/F-MNIST/the fault dataset are not available offline
+(docs/architecture.md §7); these generators produce class-structured data
+with the same shapes and dynamic range, so the *parity* experiments of
+Table II (exact STDP vs ITP-STDP ± compensation under one protocol) remain
+meaningful.
 
 All generators are pure functions of a PRNG key — reproducible, and
 `vmap`-/`scan`-friendly for streaming pipelines.
 """
 from __future__ import annotations
-
-import dataclasses
-from typing import Iterator
 
 import jax
 import jax.numpy as jnp
@@ -109,48 +107,3 @@ def synthetic_fault(key: jax.Array, n: int, *, length: int = 512,
         + jnp.where(lbl == 3, impulses, 0.0)
     sig = sig + noise * jax.random.normal(k_noise, sig.shape)
     return sig, labels
-
-
-# ---------------------------------------------------------------------------
-# LM token streams
-# ---------------------------------------------------------------------------
-
-def zipf_tokens(key: jax.Array, batch: int, seq: int, vocab: int,
-                alpha: float = 1.1) -> jax.Array:
-    """Zipf-distributed token ids (B, S) — realistic LM token marginals."""
-    ranks = jnp.arange(1, vocab + 1, dtype=jnp.float32)
-    logp = -alpha * jnp.log(ranks)
-    return jax.random.categorical(key, logp[None, None, :],
-                                  shape=(batch, seq)).astype(jnp.int32)
-
-
-@dataclasses.dataclass(frozen=True)
-class LMBatchSpec:
-    batch: int
-    seq: int
-    vocab: int
-
-
-def lm_batches(key: jax.Array, spec: LMBatchSpec,
-               n_steps: int | None = None) -> Iterator[dict]:
-    """Infinite (or n_steps-long) stream of {tokens, labels} LM batches.
-
-    labels = tokens shifted left (next-token prediction); the final column
-    is masked with -1 (ignored by the loss).
-    """
-    step = 0
-    while n_steps is None or step < n_steps:
-        key, sub = jax.random.split(key)
-        toks = zipf_tokens(sub, spec.batch, spec.seq, spec.vocab)
-        labels = jnp.concatenate(
-            [toks[:, 1:], jnp.full((spec.batch, 1), -1, jnp.int32)], axis=1)
-        yield {"tokens": toks, "labels": labels}
-        step += 1
-
-
-def host_shard(batch: dict, host_id: int, n_hosts: int) -> dict:
-    """Per-host slice of a global batch (multi-host data loading)."""
-    def slc(x):
-        per = x.shape[0] // n_hosts
-        return x[host_id * per:(host_id + 1) * per]
-    return {k: slc(v) for k, v in batch.items()}

@@ -1,1 +1,1 @@
-from repro.distributed import compression, fault_tolerance, sharding
+from repro.distributed import fault_tolerance, sharding

@@ -1,32 +1,24 @@
-"""Training launcher: ``python -m repro.launch.train --arch <id> [...]``.
+"""Training launcher for the paper's learning engine and networks.
 
-Runs end-to-end on whatever devices exist (CPU smoke scale → TPU pods): a
-synthetic-token LM run with the full production control loop — sharded
-init, jitted train step, async checkpointing, restart-on-failure,
-straggler watchdog.  For the paper's own SNN training path use
-``examples/train_snn.py`` (the learning-engine loop has no gradients).
+Two modes; one of them must be chosen:
 
-``--engine`` switches to the learning-engine workload: a population of
-engine replicas trained on random rasters with the selectable learning
-rule (``--rule itp|itp_nocomp|exact|linear|imstdp``) and weight-update
-backend (``--backend reference|fused|fused_interpret|sparse``),
-reporting synaptic-op throughput — the launcher path for exercising the
-fused Pallas datapath (and the counter-rule baselines) end-to-end.  The
-``sparse`` backend is the event-driven datapath (``--max-events`` caps
-the static event-list length per side).
+``python -m repro.launch.train --snn <net>`` trains one of the paper's
+networks (2-layer SNN, 6-layer DCSNN, 5-layer CSNN) with the shared
+train-to-accuracy loop of ``repro.train.stdp_trainer``: unsupervised STDP
+epochs with homeostasis/WTA competition and the label-assignment
+evaluation, built from the same CLI flags (``repro.launch.cli``) as
+``examples/train_snn.py``.  The conv nets drive the rule's im2col-fused
+conv kernel, the fc layers its dense kernel.
 
-``--snn <net>`` switches to the paper's network workloads (2-layer SNN,
-6-layer DCSNN, 5-layer CSNN) on the same selectable rule and backend,
-driving the shared train-to-accuracy loop of
-``repro.train.stdp_trainer`` — unsupervised STDP epochs with
-homeostasis/WTA competition and the label-assignment evaluation — through
-the same CLI builder (``repro.launch.cli``) as ``examples/train_snn.py``:
-the conv nets drive the rule's im2col-fused conv kernel, the fc layers
-its dense engine kernel — the launcher path for the whole-network fused
-datapath.  Every registered rule is kernel-backed (history rules →
-``itp_stdp``/``itp_stdp_conv``, counter rules → ``itp_counter``), so the
-full rule × backend matrix in ROADMAP.md runs from here; a rule without
-a kernel would still be rejected up front with the valid combinations.
+``python -m repro.launch.train --engine`` trains a population of engine
+replicas on random rasters and reports synaptic-op throughput.
+
+Both modes take the learning rule (``--rule itp|itp_nocomp|exact|linear|
+imstdp|mstdp``) and the weight-update backend (``--backend reference|
+fused|fused_interpret|sparse``; ``--max-events`` caps the sparse event
+lists), so the full rule × backend matrix in ROADMAP.md runs from here.
+A rule without a kernel for the chosen backend is rejected up front with
+the valid combinations.
 """
 from __future__ import annotations
 
@@ -35,16 +27,8 @@ import time
 
 import jax
 
-from repro.configs import ARCH_NAMES, get_config, get_smoke_config
-from repro.data import LMBatchSpec, lm_batches
-from repro.distributed.fault_tolerance import (FailureInjector, RunnerConfig,
-                                               TrainingRunner)
-from repro.distributed.sharding import use_mesh
 from repro.launch import cli
 from repro.launch.compile_cache import enable_compile_cache
-from repro.launch.mesh import describe, make_debug_mesh
-from repro.train import (OptimizerConfig, TrainConfig, init_training,
-                         make_train_step)
 
 
 def run_engine_training(args) -> dict:
@@ -153,14 +137,12 @@ def run_snn_training(args) -> dict:
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", choices=ARCH_NAMES, default="qwen3-0.6b")
     ap.add_argument("--engine", action="store_true",
-                    help="train the ITP-STDP learning engine instead of the "
-                         "LM stack")
+                    help="train a population of learning-engine replicas")
     # SNN-mode flags come from the shared builder (repro.launch.cli) so
     # this entry point and examples/train_snn.py declare them exactly once;
-    # --snn doubles as the mode switch (default None = LM/engine mode) and
-    # --batch is shared with the LM path (hence the LM default of 8)
+    # --snn doubles as the mode switch (default None = engine mode or a
+    # usage error); --batch keeps this launcher's default of 8
     cli.add_net_flag(ap, "--snn", default=None)
     cli.add_update_flags(ap)
     cli.add_train_flags(ap, batch_default=8)
@@ -168,96 +150,17 @@ def main():
     ap.add_argument("--engine-post", type=int, default=256)
     ap.add_argument("--replicas", type=int, default=8)
     ap.add_argument("--engine-rate", type=float, default=0.3,
-                    help="Bernoulli input spike rate (--engine and --snn "
-                         "modes)")
-    ap.add_argument("--smoke", action="store_true",
-                    help="use the reduced config (CPU-runnable)")
+                    help="Bernoulli input spike rate (--engine mode)")
     ap.add_argument("--steps", type=int, default=100)
-    ap.add_argument("--seq", type=int, default=128)
-    ap.add_argument("--lr", type=float, default=3e-4)
-    ap.add_argument("--remat", choices=("none", "full", "dots"),
-                    default="none")
-    ap.add_argument("--po2-update", action="store_true",
-                    help="ITP-AdamW: po2-quantised optimizer updates")
-    ap.add_argument("--data", type=int, default=0,
-                    help="data-parallel mesh axis (0 = no mesh)")
-    ap.add_argument("--model", type=int, default=1)
-    ap.add_argument("--ckpt-dir", default="/tmp/repro_ckpt")
-    ap.add_argument("--ckpt-every", type=int, default=50)
-    ap.add_argument("--inject-failure-at", type=int, default=-1)
-    ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args()
+    if not args.net and not args.engine:
+        ap.error("choose a mode: --snn <net> or --engine")
     enable_compile_cache()
 
     if args.net:
         run_snn_training(args)
-        return
-    if args.engine:
+    else:
         run_engine_training(args)
-        return
-
-    cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
-    opt_cfg = OptimizerConfig(lr=args.lr, total_steps=args.steps,
-                              warmup_steps=max(args.steps // 20, 5),
-                              po2_update=args.po2_update)
-    train_cfg = TrainConfig(remat=args.remat)
-
-    mesh = None
-    if args.data > 0:
-        mesh = make_debug_mesh(data=args.data, model=args.model)
-        print(f"mesh: {describe(mesh)}")
-
-    ctx = use_mesh(mesh) if mesh is not None else use_mesh(None)
-    with ctx:
-        params, opt_state = init_training(jax.random.PRNGKey(0), cfg, opt_cfg,
-                                          mesh)
-        step_fn = jax.jit(make_train_step(cfg, opt_cfg, train_cfg, mesh))
-
-        spec = LMBatchSpec(batch=args.batch, seq=args.seq,
-                           vocab=cfg.vocab_size)
-
-        def batch_for(step: int):
-            return next(lm_batches(jax.random.PRNGKey(1000 + step), spec,
-                                   n_steps=1))
-
-        state = {"params": params, "opt": opt_state}
-
-        def wrapped(state, batch):
-            p, o, metrics = step_fn(state["params"], state["opt"], batch)
-            return {"params": p, "opt": o}, metrics
-
-        runner = TrainingRunner(
-            RunnerConfig(ckpt_dir=args.ckpt_dir,
-                         ckpt_every=args.ckpt_every),
-            wrapped, batch_for)
-        injector = None
-        if args.inject_failure_at >= 0:
-            injector = FailureInjector({args.inject_failure_at})
-
-        t0 = time.time()
-        n_logged = [0]
-
-        orig_step = runner.step_fn
-
-        def logging_step(state, batch):
-            out, metrics = orig_step(state, batch)
-            n = n_logged[0]
-            if n % args.log_every == 0:
-                loss = float(metrics["loss"])
-                toks = float(metrics["tokens"]) * args.log_every
-                dt = time.time() - t0
-                print(f"step {n:5d}  loss {loss:.4f}  "
-                      f"lr {float(metrics['lr']):.2e}  "
-                      f"gnorm {float(metrics['grad_norm']):.3f}  "
-                      f"({n / max(dt, 1e-9):.2f} it/s)", flush=True)
-            n_logged[0] += 1
-            return out, metrics
-
-        runner.step_fn = logging_step
-        state = runner.run(state, args.steps, injector)
-        print(f"done: {args.steps} steps in {time.time() - t0:.1f}s; "
-              f"restarts={runner.restarts}; "
-              f"stragglers={len(runner.watchdog.stragglers)}")
 
 
 if __name__ == "__main__":

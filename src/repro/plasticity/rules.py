@@ -13,8 +13,7 @@ Two families, matching the paper's §I taxonomy:
     a per-neuron last-spike counter (saturating at ``depth``); on an
     update the per-pair timing difference is formed and a window function
     evaluated per synapse — the O(n²) transcendental work Tables III-V
-    monetise.  ``exact`` (base-e exponential, [26]/[28]-style — the
-    CounterEngine of ``repro.core.baseline`` folded into the rule API),
+    monetise.  ``exact`` (base-e exponential, [26]/[28]-style),
     ``linear`` (the PWL approximation of [24]) and ``imstdp`` (the
     integer-grid LUT of [23]).  The window semantics live in
     ``repro.kernels.itp_counter.ref`` (shared with the fused Pallas

@@ -1,7 +1,3 @@
-from repro.train.optimizer import (OptimizerConfig, OptState, adamw_update,
-                                   init_opt_state, lr_schedule)
-from repro.train.train_step import (TrainConfig, init_training, lm_loss,
-                                    make_train_step, batch_shardings)
 from repro.train.stdp_trainer import (TrainerConfig, assign_labels,
                                       assignment_accuracy, assignment_predict,
                                       evaluate, train_to_accuracy)

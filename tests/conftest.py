@@ -1,5 +1,5 @@
-"""Shared fixtures.  Tests run on the single CPU device — the 512-device
-override lives ONLY in repro.launch.dryrun (never set globally here)."""
+"""Shared fixtures.  Tests run on the single CPU device; the multi-device
+tests force extra host devices in a subprocess, never here."""
 import jax
 import pytest
 

@@ -3,8 +3,8 @@
 Pins (a) the exact finding set each rule produces on the fixture tree
 under ``tests/fixtures/lint/`` (one violation + a clean twin per rule),
 (b) the ``--explain`` texts, (c) that the committed allowlist matches
-the repo's *actual* baseline — empty for R1–R6 and R8, because the
-satellite fixes removed every real violation — and (d) the jaxpr-audit contracts
+the repo's *actual* baseline — empty for every rule, because every real
+violation was fixed or its module removed — and (d) the jaxpr-audit contracts
 on a slice of the matrix (the full matrix runs as the ``static_audit``
 benchmark and in the CI gate).  The doc-lint layer (D1 snippet
 execution, D2 link resolution) is covered on synthetic doc trees; the
@@ -138,17 +138,15 @@ def test_cli_clean_on_repo(capsys):
 
 def test_committed_allowlist_matches_repo_baseline():
     """The committed baseline IS the repo's current finding set: nothing
-    new, nothing stale, and R1–R6 + R8 empty (the satellite fixes landed)."""
+    new, nothing stale, and every rule's baseline empty."""
     findings = run_lint(REPO_ROOT)
     allow = load_allowlist(ALLOWLIST)
     new, stale = apply_allowlist(findings, allow)
     assert new == [], [f.render() for f in new]
     assert stale == []
-    for rule in ("R1", "R2", "R3", "R4", "R5", "R6", "R8"):
+    for rule in ("R1", "R2", "R3", "R4", "R5", "R6", "R7", "R8"):
         msg = f"{rule} baseline must stay empty — fix the violation instead of allowlisting"
         assert not allow.get(rule), msg
-    expected = {"repro.configs.qwen3_0_6b", "repro.models.config"}
-    assert {e["module"] for e in allow["R7"]} >= expected
 
 
 def test_allowlist_requires_justification(tmp_path):

@@ -2,9 +2,8 @@
 import jax.numpy as jnp
 import numpy as np
 
-from repro.data import (LMBatchSpec, Prefetcher, encode_batch, host_shard,
-                        lm_batches, spike_stream, synthetic_digits,
-                        synthetic_fashion, synthetic_fault, zipf_tokens)
+from repro.data import (Prefetcher, encode_batch, spike_stream,
+                        synthetic_digits, synthetic_fashion, synthetic_fault)
 
 
 def test_digits_shapes_and_range(key):
@@ -49,33 +48,6 @@ def test_fault_classes_differ_spectrally(key):
     k3 = np.mean([kurt(x[i, :, 0]) for i in np.where(y == 3)[0][:20]])
     k0 = np.mean([kurt(x[i, :, 0]) for i in np.where(y == 0)[0][:20]])
     assert k3 > k0
-
-
-def test_zipf_tokens(key):
-    t = zipf_tokens(key, 4, 512, vocab=1000)
-    assert t.shape == (4, 512)
-    assert int(t.min()) >= 0 and int(t.max()) < 1000
-    # zipf: low ids much more frequent
-    flat = np.asarray(t).ravel()
-    assert (flat < 10).mean() > (flat >= 500).mean()
-
-
-def test_lm_batches_labels_shifted(key):
-    spec = LMBatchSpec(batch=2, seq=16, vocab=100)
-    b = next(lm_batches(key, spec))
-    np.testing.assert_array_equal(np.asarray(b["labels"][:, :-1]),
-                                  np.asarray(b["tokens"][:, 1:]))
-    assert (np.asarray(b["labels"][:, -1]) == -1).all()
-
-
-def test_host_shard():
-    batch = {"tokens": jnp.arange(8)[:, None]}
-    s0 = host_shard(batch, 0, 2)
-    s1 = host_shard(batch, 1, 2)
-    np.testing.assert_array_equal(np.asarray(s0["tokens"]).ravel(),
-                                  [0, 1, 2, 3])
-    np.testing.assert_array_equal(np.asarray(s1["tokens"]).ravel(),
-                                  [4, 5, 6, 7])
 
 
 def test_encode_batch_rate(key):

@@ -53,7 +53,7 @@ def test_paper_metrics_within_band():
 
     RMSE is matched tightly (it is protocol-free); the equilibrium and
     convergence errors depend on unpublished protocol details — we assert
-    the same order of magnitude (DESIGN.md §7).
+    the same order of magnitude (docs/architecture.md §7).
     """
     m = paper_metrics(n_steps=1500)
     assert abs(m["update_curve_rmse"] - 0.094753) < 5e-4

@@ -3,7 +3,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
 
 from repro.core.history import init_history, push
 from repro.core.lif import LIFParams, LIFState, lif_init, lif_step
@@ -281,64 +280,3 @@ def test_lif_kernel_1d_api(key):
     i_in = jax.random.uniform(key, (40,))
     s1, sp1 = lif_step_kernel(st, i_in, p, use_kernel=True, interpret=True)
     assert s1.v.shape == (40,) and sp1.shape == (40,)
-
-
-# ---------------------------------------------------------------------------
-# po2 quantiser kernel
-# ---------------------------------------------------------------------------
-
-@settings(max_examples=100, deadline=None)
-@given(x=st.floats(-1e6, 1e6, allow_nan=False, width=32))
-def test_po2_roundtrip_properties(x):
-    from repro.kernels.po2_quant.ref import po2_roundtrip_ref
-    q = float(po2_roundtrip_ref(jnp.asarray(x, jnp.float32)))
-    if x == 0.0 or abs(x) < 1.2e-38:   # zero / f32-subnormal underflow → 0
-        assert q == 0.0 or np.sign(q) == np.sign(x)
-    else:
-        assert np.sign(q) == np.sign(x)
-        if 1e-15 < abs(x) < 1e15:   # in exponent range
-            # nearest po2 in log space: ratio within [2^-0.5, 2^0.5]
-            ratio = q / x
-            assert 0.7071 / 1.001 <= ratio <= 1.4143 * 1.001
-            # q is an exact power of two
-            m, e = np.frexp(abs(q))
-            assert m == 0.5
-
-
-@pytest.mark.parametrize("n", [
-    128, 500, pytest.param(4096, marks=pytest.mark.slow)])
-def test_po2_kernel_vs_ref(key, n):
-    from repro.kernels.po2_quant.kernel import po2_decode, po2_encode
-    from repro.kernels.po2_quant.ref import po2_decode_ref, po2_encode_ref
-    x = jax.random.normal(key, (n,)) * jnp.exp(
-        jax.random.uniform(jax.random.fold_in(key, 1), (n,), minval=-20,
-                           maxval=20))
-    pad = (-n) % 128
-    xp = jnp.pad(x, (0, pad))
-    enc_k = po2_encode(xp, tile=128, interpret=True)[:n]
-    enc_r = po2_encode_ref(x)
-    np.testing.assert_array_equal(np.asarray(enc_k), np.asarray(enc_r))
-    dec_k = po2_decode(jnp.pad(enc_r, (0, pad)), tile=128,
-                       interpret=True)[:n]
-    dec_r = po2_decode_ref(enc_r)
-    np.testing.assert_allclose(np.asarray(dec_k), np.asarray(dec_r))
-
-
-def test_po2_quantize_tree(key):
-    from repro.kernels.po2_quant.ops import po2_quantize_tree
-    tree = {"a": jax.random.normal(key, (37,)),
-            "b": {"c": jax.random.normal(jax.random.fold_in(key, 1), (8, 9))}}
-    out = po2_quantize_tree(tree)
-    for leaf in jax.tree_util.tree_leaves(out):
-        vals = np.abs(np.asarray(leaf))
-        nz = vals[vals > 0]
-        m, _ = np.frexp(nz)
-        assert (m == 0.5).all()
-
-
-def test_po2_quantize_kernel_path(key):
-    from repro.kernels.po2_quant.ops import po2_quantize
-    x = jax.random.normal(key, (77,))
-    a = po2_quantize(x, use_kernel=True, interpret=True)
-    b = po2_quantize(x, use_kernel=False)
-    np.testing.assert_allclose(np.asarray(a), np.asarray(b))

@@ -1,5 +1,5 @@
-"""Unified LearningRule API: registry, rule × backend matrix, trajectory
-pins, and the CounterEngine deprecation shims.
+"""Unified LearningRule API: registry, rule × backend matrix and trajectory
+pins.
 
 The load-bearing contracts:
 
@@ -296,46 +296,3 @@ def test_launcher_engine_mode_runs_counter_rule():
     summary = run_engine_training(args)
     assert summary["rule"] == "exact"
     assert summary["sops_per_s"] > 0
-
-
-# ---------------------------------------------------------------------------
-# CounterEngine deprecation shims
-# ---------------------------------------------------------------------------
-
-def test_counter_engine_aliases_stay_green(key):
-    from repro.core.baseline import (CounterEngineConfig,
-                                     counter_engine_step,
-                                     init_counter_engine,
-                                     run_counter_engine)
-
-    # every alias is deprecated and must say where to go instead …
-    with pytest.warns(DeprecationWarning, match=r"rule='exact'"):
-        cfg = CounterEngineConfig(n_pre=12, n_post=8, window=7)
-    assert isinstance(cfg, EngineConfig)
-    assert cfg.rule == "exact" and cfg.depth == 8
-    with pytest.warns(DeprecationWarning, match="init_engine"):
-        state = init_counter_engine(key, cfg)
-    train = jax.random.bernoulli(key, 0.4, (25, 12))
-    with pytest.warns(DeprecationWarning, match="run_engine"):
-        s_alias, post_alias = run_counter_engine(state, train, cfg)
-    # single-step alias too
-    with pytest.warns(DeprecationWarning, match="engine_step"):
-        s1, p1 = counter_engine_step(state, train[0], cfg)
-    assert p1.shape == (8,)
-    # … but the deprecated path must still compute the registry path
-    # the shim is the unified engine: same trajectory as the direct config
-    direct = EngineConfig(n_pre=12, n_post=8, depth=8, rule="exact")
-    s_direct, post_direct = run_engine(init_engine(key, direct), train,
-                                       direct)
-    np.testing.assert_array_equal(np.asarray(s_alias.w),
-                                  np.asarray(s_direct.w))
-    np.testing.assert_array_equal(np.asarray(post_alias),
-                                  np.asarray(post_direct))
-
-
-def test_counter_engine_aliases_reject_wrong_rule(key):
-    from repro.core.baseline import init_counter_engine
-
-    with pytest.warns(DeprecationWarning), \
-         pytest.raises(ValueError, match="exact"):
-        init_counter_engine(key, EngineConfig(rule="itp"))

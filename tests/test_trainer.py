@@ -242,6 +242,18 @@ def test_launcher_snn_mode_reports_accuracy():
     assert 0.0 <= summary["final_accuracy"] <= 1.0
 
 
+def test_launcher_without_a_mode_is_a_usage_error(monkeypatch, capsys):
+    """With neither --snn nor --engine the launcher picks no mode on its own."""
+    from repro.launch import train
+
+    monkeypatch.setattr("sys.argv", ["train.py", "--steps", "4"])
+    with pytest.raises(SystemExit) as exc:
+        train.main()
+    assert exc.value.code == 2
+    err = capsys.readouterr().err
+    assert "--snn" in err and "--engine" in err
+
+
 # ---------------------------------------------------------------------------
 # Validator parity: EngineConfig and SNNConfig share one message surface
 # ---------------------------------------------------------------------------
