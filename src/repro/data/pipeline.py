@@ -19,27 +19,43 @@ from repro import tracing
 from repro.core.encoding import minmax_normalise, rate_code
 
 
+@partial(jax.jit, static_argnums=2)
+def _encode(key: jax.Array, x: jax.Array, t_steps: int) -> jax.Array:
+    norm = minmax_normalise(x.reshape(x.shape[0], -1), axis=-1)
+    return rate_code(key, norm, t_steps)               # (T, B, N)
+
+
 @partial(annotate_function, name=tracing.ENCODE)
 def encode_batch(key: jax.Array, x: jax.Array, t_steps: int) -> jax.Array:
     """(B, ...) floats → (T, B, features) {0,1} spikes.
 
     Per-sample min-max normalisation (eq. 28) then Bernoulli rate coding
-    (eq. 29); feature dims are flattened.
+    (eq. 29); feature dims are flattened.  One compiled program per input
+    shape and ``t_steps``, bit for bit the eager composition of
+    ``minmax_normalise`` and ``rate_code``.
     """
-    B = x.shape[0]
-    flat = x.reshape(B, -1)
-    norm = minmax_normalise(flat, axis=-1)
-    return rate_code(key, norm, t_steps)               # (T, B, N)
+    return _encode(key, x, t_steps)
+
+
+@jax.jit
+def _split3(key: jax.Array) -> tuple[jax.Array, jax.Array, jax.Array]:
+    key, k_data, k_enc = jax.random.split(key, 3)
+    return key, k_data, k_enc
 
 
 def spike_stream(key: jax.Array,
                  sampler: Callable[[jax.Array, int], tuple[jax.Array, jax.Array]],
                  *, batch: int, t_steps: int,
                  n_steps: int | None = None) -> Iterator[dict]:
-    """Stream of {spikes (T,B,N), labels (B,)} batches from a sampler."""
+    """Stream of {spikes (T,B,N), labels (B,)} batches from a sampler.
+
+    Per raster: one compiled key split, the sampler (its own call, so a
+    sampler that closes over a device pool does not bake it into a
+    program), one compiled encode.
+    """
     step = 0
     while n_steps is None or step < n_steps:
-        key, k_data, k_enc = jax.random.split(key, 3)
+        key, k_data, k_enc = _split3(key)
         with TraceAnnotation(tracing.SAMPLE):
             x, labels = sampler(k_data, batch)
         yield {"spikes": encode_batch(k_enc, x, t_steps), "labels": labels}

@@ -1,8 +1,11 @@
 """Synthetic data generators + the spike-encoding pipeline."""
+import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
-from repro.data import (Prefetcher, encode_batch, spike_stream,
+from repro.core.encoding import minmax_normalise, rate_code
+from repro.data import (Prefetcher, encode_batch, pipeline, spike_stream,
                         synthetic_digits, synthetic_fashion, synthetic_fault)
 
 
@@ -67,6 +70,88 @@ def test_spike_stream(key):
     assert len(batches) == 3
     assert batches[0]["spikes"].shape == (6, 4, 784)
     assert batches[0]["labels"].shape == (4,)
+
+
+def _eager_encode(k, x, t_steps):
+    return rate_code(k, minmax_normalise(x.reshape(x.shape[0], -1), axis=-1),
+                     t_steps)
+
+
+def _samples(key, batch, shape):
+    """Rows of different offsets and scales; the last row constant when
+    there are several, so the min-max's floor on the range is taken."""
+    k1, k2, k3 = jax.random.split(key, 3)
+    x = jax.random.normal(k1, (batch, *shape))
+    scale = jax.random.uniform(k2, (batch,) + (1,) * len(shape), minval=0.1, maxval=9.0)
+    shift = jax.random.normal(k3, (batch,) + (1,) * len(shape))
+    x = x * scale + shift
+    return x.at[-1].set(0.25) if batch > 1 else x
+
+
+@pytest.mark.parametrize("batch", [1, 16, 256])
+@pytest.mark.parametrize("shape", [(28, 28), (512, 2)], ids=["image", "window"])
+def test_encode_batch_equals_eager_composition(key, shape, batch):
+    x = _samples(key, batch, shape)
+    k_enc = jax.random.fold_in(key, 7)
+    got = encode_batch(k_enc, x, 3)
+    want = _eager_encode(k_enc, x, 3)
+    assert got.shape == want.shape == (3, batch, shape[0] * shape[1])
+    assert got.dtype == want.dtype == jnp.uint8
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_encode_batch_keeps_the_normalisation_bits(key):
+    """Inputs whose normalised values land on their own uniform draws: a
+    one-ulp change of the min-max arithmetic (a reciprocal multiply in place
+    of the divide, say) flips spikes that random inputs would not."""
+    k_enc = jax.random.fold_in(key, 5)
+    u = jax.random.uniform(k_enc, (1, 64, 784))[0]
+    lo, r = -1.7, 3.3
+    x = (lo + u * r).at[:, 0].set(lo).at[:, 1].set(lo + r)
+    on_edge = minmax_normalise(x, axis=-1) == u
+    assert int(on_edge.sum()) > 1000
+    np.testing.assert_array_equal(np.asarray(encode_batch(k_enc, x, 1)),
+                                  np.asarray(_eager_encode(k_enc, x, 1)))
+
+
+@jax.jit
+def _take(x, y, k):
+    i = jax.random.randint(k, (4,), 0, x.shape[0])
+    return x[i], y[i]
+
+
+def test_spike_stream_equals_eager_loop(key):
+    """The compiled split and encode give the rasters and labels of the
+    plain loop: eager split, the sampler, the eager encode."""
+    x_pool, y_pool = synthetic_digits(jax.random.PRNGKey(3), 64)
+    sampler = lambda k, n: _take(x_pool, y_pool, k)  # noqa: E731
+    got = list(spike_stream(key, sampler, batch=4, t_steps=5, n_steps=5))
+    assert len(got) == 5
+    k = key
+    for b in got:
+        k, k_data, k_enc = jax.random.split(k, 3)
+        x, labels = sampler(k_data, 4)
+        np.testing.assert_array_equal(np.asarray(b["spikes"]),
+                                      np.asarray(_eager_encode(k_enc, x, 5)))
+        np.testing.assert_array_equal(np.asarray(b["labels"]), np.asarray(labels))
+
+
+def test_encode_batch_compiles_once_per_shape(key):
+    """Fresh keys and values at one shape reuse one compiled program: a
+    retrace per raster would be a compile inside a measured window."""
+    x = _samples(key, 5, (7, 3))
+    before = pipeline._encode._cache_size()
+    for i in range(10):
+        encode_batch(jax.random.fold_in(key, i), x * (i + 1), 9)
+    n_encode = pipeline._encode._cache_size()
+    assert n_encode == before + 1
+    # the stream's split compiles once too, and its encode reuses the program
+    before = pipeline._split3._cache_size()
+    for _ in spike_stream(jax.random.PRNGKey(11), lambda k, n: (x, k),
+                          batch=5, t_steps=9, n_steps=10):
+        pass
+    assert pipeline._split3._cache_size() <= before + 1
+    assert pipeline._encode._cache_size() == n_encode
 
 
 def test_prefetcher_preserves_order():
